@@ -157,6 +157,9 @@ def main(argv=None) -> int:
     ap.add_argument("--workers", type=int, default=4, help="in-process daemon width")
     ap.add_argument("--out", default=None, help="write the result JSON here")
     args = ap.parse_args(argv)
+    from repro.launch.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
 
     shapes = SMOKE_SHAPES if args.smoke else FULL_SHAPES
     requests = args.requests if args.requests is not None else (4 if args.smoke else 12)

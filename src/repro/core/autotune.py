@@ -24,7 +24,7 @@ containers without a plan decode with the default cubic/md steps.
 
 On the GPU the paper balances thread blocks per level; the TPU analogue is
 the sample volume itself (each per-level trial is a handful of small
-batched matmuls), kept at the paper's 0.2 % budget — except that small
+batched stencil passes), kept at the paper's 0.2 % budget — except that small
 fields (<= EXHAUSTIVE_BLOCKS blocks) are sampled exhaustively, which makes
 the greedy per-level selection exact for the bench-suite fields.
 """
@@ -41,7 +41,7 @@ import numpy as np
 from . import blocks as _blk
 from .lossless import orchestrate as orc
 from .lossless import pipelines as _pipelines
-from .predictor import CENTER, RADIUS, _anchor_mask, _predict, quantize_pred
+from .predictor import CENTER, RADIUS, _anchor_mask, fence, fence_zero, predict, quant_steps, quantize_pred
 from .reorder import reorder_codes_batch
 from .serial import pack_obj, unpack_obj
 from .stencils import SCHEMES, SPLINES, build_steps
@@ -227,26 +227,27 @@ def plan_signature(shape, dtype, eb: float, eb_mode: str, bucket=(), *, extra=()
 
 
 # ------------------------------------------------------------ trial passes
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def _level_pass(recon, orig, twoeb, steps, update: bool):
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _level_pass(recon, orig, twoeb, inv2eb, steps, update: bool):
     """Run one level's steps; return (new_recon, sum |orig-pred| over targets).
 
     Legacy scorer for :func:`autotune` (absolute-error argmin).
     """
     err = jnp.zeros((), jnp.float32)
+    z = fence_zero(twoeb)
     for step in steps:
-        pred = _predict(recon, step)
+        pred = predict(recon, step, z)
         m = jnp.asarray(step.mask)
         err = err + jnp.sum(jnp.where(m, jnp.abs(orig - pred), 0.0))
-        q = jnp.rint((orig - pred) / twoeb)
+        q = jnp.rint((orig - pred) * inv2eb)
         outl = jnp.abs(q) > RADIUS
-        rec = jnp.where(outl, orig, pred + q * twoeb)
+        rec = jnp.where(outl, orig, pred + fence(q * twoeb, z))
         recon = jnp.where(m, rec, recon)
     return recon, err
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _level_codes_pass(recon, orig, twoeb, steps):
+@functools.partial(jax.jit, static_argnums=(4,))
+def _level_codes_pass(recon, orig, twoeb, inv2eb, steps):
     """One level with quantization feedback, returning what the encoder
     would emit: (new_recon, codes) where ``codes`` carries the uint8
     quantization code at this level's target points and -1 elsewhere.
@@ -255,10 +256,10 @@ def _level_codes_pass(recon, orig, twoeb, steps):
     bit-identical to the stream the compressor then produces.
     """
     codes = jnp.full(orig.shape, -1, jnp.int32)
-    inv2eb = 1.0 / twoeb
+    z = fence_zero(twoeb)
     for step in steps:
-        pred = _predict(recon, step)
-        code, _, rec = quantize_pred(orig, pred, twoeb, inv2eb)
+        pred = predict(recon, step, z)
+        code, _, rec = quantize_pred(orig, pred, twoeb, inv2eb, z)
         m = jnp.asarray(step.mask)
         recon = jnp.where(m, rec, recon)
         codes = jnp.where(m, code, codes)
@@ -328,14 +329,14 @@ def autotune(blocks: np.ndarray, twoeb: float, levels=(8, 4, 2, 1), anchor_every
     sample = jnp.asarray(blocks if presampled else blocks[legacy_sample_indices(nb)])
     am = jnp.asarray(_anchor_mask(sample.shape[1:], anchor_every))
     recon = jnp.where(am, sample, 0.0)
-    twoeb = jnp.float32(twoeb)
+    qs = quant_steps(0.5 * twoeb)
     chosen_splines, chosen_schemes = [], []
     for s in levels:
         best = None
         for spline in SPLINES:
             for scheme in SCHEMES:
                 steps = build_steps(ndim, B, (s,), (spline,), (scheme,))
-                _, err = _level_pass(recon, sample, twoeb, steps, False)
+                _, err = _level_pass(recon, sample, *qs, steps, False)
                 err = float(err)
                 if best is None or err < best[0]:
                     best = (err, spline, scheme)
@@ -343,7 +344,7 @@ def autotune(blocks: np.ndarray, twoeb: float, levels=(8, 4, 2, 1), anchor_every
         chosen_splines.append(spline)
         chosen_schemes.append(scheme)
         steps = build_steps(ndim, B, (s,), (spline,), (scheme,))
-        recon, _ = _level_pass(recon, sample, twoeb, steps, True)
+        recon, _ = _level_pass(recon, sample, *qs, steps, True)
     return tuple(chosen_splines), tuple(chosen_schemes)
 
 
@@ -364,7 +365,7 @@ def _anchor_count(field_shape: tuple[int, ...] | None, sample_shape: tuple[int, 
     return n_blocks * int(np.count_nonzero(_anchor_mask(sample_shape, stride)))
 
 
-def _greedy_levels(sample, twoeb_j, stride: int, ndim: int, B: int):
+def _greedy_levels(sample, qs, stride: int, ndim: int, B: int):
     """Per-level greedy sweep with quantization feedback.
 
     Returns (splines, schemes, per-level code grids big-stride-first).
@@ -379,7 +380,7 @@ def _greedy_levels(sample, twoeb_j, stride: int, ndim: int, B: int):
         for spline in candidate_splines():
             for scheme in candidate_schemes(ndim):
                 steps = build_steps(ndim, B, (s,), (spline,), (scheme,))
-                r2, codes = _level_codes_pass(recon, sample, twoeb_j, steps)
+                r2, codes = _level_codes_pass(recon, sample, *qs, steps)
                 codes = np.asarray(codes)
                 emits = _level_emits(codes)
                 hist = np.bincount(emits, minlength=256)
@@ -393,7 +394,7 @@ def _greedy_levels(sample, twoeb_j, stride: int, ndim: int, B: int):
     return tuple(splines_sel), tuple(schemes_sel), grids
 
 
-def _eval_config(sample, twoeb_j, stride: int, splines, schemes, ndim: int, B: int):
+def _eval_config(sample, qs, stride: int, splines, schemes, ndim: int, B: int):
     """Full-hierarchy evaluation of a (splines, schemes) config with
     feedback; returns per-level code grids. Runs level by level so every
     jitted pass is shared with the greedy sweep's cache."""
@@ -402,7 +403,7 @@ def _eval_config(sample, twoeb_j, stride: int, splines, schemes, ndim: int, B: i
     grids: list[np.ndarray] = []
     for s, spline, scheme in zip(levels_for_stride(stride), splines, schemes):
         steps = build_steps(ndim, B, (s,), (spline,), (scheme,))
-        recon, codes = _level_codes_pass(recon, sample, twoeb_j, steps)
+        recon, codes = _level_codes_pass(recon, sample, *qs, steps)
         grids.append(np.asarray(codes))
     return grids
 
@@ -452,7 +453,7 @@ def autotune_plan(
         nb, sample_np = blocks.shape[0], _sample_blocks(blocks)
     ns = sample_np.shape[0]
     sample = jnp.asarray(sample_np)
-    twoeb_j = jnp.float32(twoeb)
+    qs = quant_steps(0.5 * twoeb)  # (twoeb, inv2eb) as the encoder quantizes
     scale = nb / ns  # sampled code bits -> full-field code bits
     n_points = nb * B**ndim  # normalization only; comparisons use totals
     exact = ns == nb and field_shape is not None
@@ -478,14 +479,14 @@ def autotune_plan(
     for stride in anchor_strides:
         anchor_bits = _anchor_count(field_shape, sample.shape[1:], nb, stride) * ANCHOR_BITS
         nlev = len(levels_for_stride(stride))
-        g_splines, g_schemes, g_grids = _greedy_levels(sample, twoeb_j, stride, ndim, B)
+        g_splines, g_schemes, g_grids = _greedy_levels(sample, qs, stride, ndim, B)
         consider(stride, g_splines, g_schemes, g_grids, anchor_bits, "greedy")
         for spline in candidate_splines():
             for scheme in candidate_schemes(ndim):
                 cfg = ((spline,) * nlev, (scheme,) * nlev)
                 if cfg == (g_splines, g_schemes):
                     continue  # already scored as the greedy plan
-                grids = _eval_config(sample, twoeb_j, stride, *cfg, ndim, B)
+                grids = _eval_config(sample, qs, stride, *cfg, ndim, B)
                 consider(stride, *cfg, grids, anchor_bits, "uniform")
 
     order = sorted(cands, key=lambda c: (c["est"], c["label"]))[: max(1, max_trials)]

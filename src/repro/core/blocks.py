@@ -123,15 +123,16 @@ def _scatter_index(shape_padded: tuple[int, ...], stride: int = ANCHOR_STRIDE):
     return jnp.asarray(idx.reshape(-1))  # uncommitted: follows the operand's device
 
 
-def scatter_blocks_batch_jnp(blocks, batch: int, shape_padded: tuple[int, ...], stride: int = ANCHOR_STRIDE):
-    """Device twin of scatter_blocks_batch: one cached-index gather.
+def scatter_blocks_batch_jnp(blocks, idx, batch: int, shape_padded: tuple[int, ...]):
+    """Device twin of scatter_blocks_batch: one gather by ``idx``, the
+    :func:`_scatter_index` of ``shape_padded`` (an argument, so a jitted
+    caller does not bake it in as a constant).
 
     ``blocks`` is a jax array shaped (batch*nb, B..); returns the (batch,
     *padded) grid as a device array, bit-identical to the numpy scatter.
     """
     import jax.numpy as jnp
 
-    idx = _scatter_index(tuple(int(s) for s in shape_padded), stride)
     flat = blocks.reshape(batch, -1)
     return jnp.take(flat, idx, axis=1).reshape((batch,) + tuple(shape_padded))
 
@@ -184,12 +185,13 @@ def _anchor_index(shape_padded: tuple[int, ...], stride: int = ANCHOR_STRIDE):
     return jnp.asarray(idx.reshape(-1)), jnp.asarray(mask.reshape(-1))
 
 
-def place_anchors_batch_jnp(shape_padded: tuple[int, ...], anchors, stride: int = ANCHOR_STRIDE):
+def place_anchors_batch_jnp(shape_padded: tuple[int, ...], anchors, ix):
     """Device twin of place_anchors_batch; ``anchors`` is a jax array
-    (batch, *anchor_shape); returns (batch, *padded) f32, bit-identical."""
+    (batch, *anchor_shape), ``ix`` the :func:`_anchor_index` pair;
+    returns (batch, *padded) f32, bit-identical."""
     import jax.numpy as jnp
 
-    idx, mask = _anchor_index(tuple(int(s) for s in shape_padded), stride)
+    idx, mask = ix
     flat = anchors.astype(jnp.float32).reshape(anchors.shape[0], -1)
     rows = jnp.take(flat, idx, axis=1)
     out = jnp.where(mask[None, :], rows, jnp.float32(0.0))

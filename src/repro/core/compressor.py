@@ -57,19 +57,26 @@ The predictor backend is selected by ``CompressorSpec.backend``:
 ``"jax"`` (default) uses the pure-jnp engine in repro.core.predictor;
 ``"pallas"`` routes compression through the fused Pallas TPU kernel in
 repro.kernels.interp3d (interpret mode off-TPU, compiled on TPU; 3-D
-fields only — other ranks fall back to jax). Decompression always replays
-through the jax engine; both backends quantize with the same arithmetic,
-so the error-bound contract holds either way.
+fields only — other ranks run the jax engine). Decompression always
+replays through the jax engine; both backends run the same f32 operation
+sequence, so the error-bound contract holds either way.
+
+A device, Pallas or shard failure raises to the caller: there is no
+silent retry on another implementation. Which engine runs is a choice
+made up front (``CompressorSpec.engine``), recorded in
+``last_telemetry``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import struct
 import threading
 import time
 import zlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -89,8 +96,8 @@ from .autotune import (
 )
 from .lossless import orchestrate, pipelines
 from .lossless.flenc import fl_decode, fl_encode
-from .predictor import compress_blocks, decompress_blocks
-from .reorder import reorder_codes_batch, restore_codes_batch, restore_codes_batch_device
+from .predictor import ARITH, compress_blocks, decompress_blocks, decompress_blocks_matmul, quant_steps
+from .reorder import _restore_gather, reorder_codes_batch, restore_codes_batch, restore_codes_batch_device
 from .serial import pack_obj, unpack_obj
 from .stencils import SPLINES, build_steps
 
@@ -393,6 +400,22 @@ def _sections_unpack(buf: bytes):
     raise ValueError(f"bad container magic {bytes(buf[:6])!r}; expected {MAGIC!r} or {MAGIC_V1!r}")
 
 
+@functools.partial(jax.jit, static_argnames=("batch", "padded", "stride", "steps"))
+def _reconstruct_device(seq, anc, ovflat, twoeb, restore_ix, anchor_ix, scatter_ix, *,
+                        batch, padded, stride, steps):
+    """The device decode tail as one program: restore the code grid,
+    place anchors and outliers, gather blocks, replay the predictor,
+    scatter back. One jit lets XLA plan (and free) the ~10 field-sized
+    intermediates; the cached gather indices come in as arguments, not
+    as constants baked into the program."""
+    cgrid = restore_codes_batch_device(seq, restore_ix, batch, padded, fill=128)
+    agrid = blk.place_anchors_batch_jnp(padded, anc, anchor_ix)
+    gather = lambda g: blk.gather_blocks_batch_jnp(g, blk.ANCHOR_STRIDE)
+    recon_b = decompress_blocks(gather(cgrid), gather(agrid), gather(ovflat.reshape((batch,) + padded)),
+                                twoeb, steps, stride)
+    return blk.scatter_blocks_batch_jnp(recon_b, scatter_ix, batch, padded)
+
+
 class _PerCallState(threading.local):
     """Per-thread observability slots of a (possibly shared) Compressor.
 
@@ -423,14 +446,10 @@ class Compressor:
         #     "auto" compress() on this thread (observability only; the
         #     container header records everything decode needs).
         #   last_telemetry — reset by compress() and decompress(); records
-        #     the requested backend/engine plus every fallback the ladder
-        #     took (pallas predictor -> jax, device encode/reorder/pack/
-        #     decode -> numpy), the plan-cache outcome ("plan_cache":
-        #     "hit"/"miss") and the chosen pipeline. decompress()
-        #     additionally records a "decode" dict (engine, out, seconds,
-        #     bytes, mbps). The bit-identity contract makes fallbacks
-        #     invisible in the output bytes, so this dict is how
-        #     degradation stays observable.
+        #     the requested backend/engine, the plan-cache outcome
+        #     ("plan_cache": "hit"/"miss") and the chosen pipeline.
+        #     decompress() additionally records a "decode" dict (engine,
+        #     out, seconds, bytes, mbps).
         #   last_damage — reset by decompress(); under on_error="skip"/
         #     "fill" records the DamageReport and the per-chunk intact
         #     mask of a salvaged v3 container (None = fully intact).
@@ -472,14 +491,8 @@ class Compressor:
 
     def _telemetry(self) -> dict:
         if self.last_telemetry is None:
-            self.last_telemetry = {"backend": self.spec.backend, "engine": self.spec.engine,
-                                   "fallbacks": []}
+            self.last_telemetry = {"backend": self.spec.backend, "engine": self.spec.engine}
         return self.last_telemetry
-
-    def _record_fallback(self, point: str, src: str, dst: str, err: Exception) -> None:
-        self._telemetry()["fallbacks"].append(
-            {"point": point, "from": src, "to": dst, "error": repr(err)}
-        )
 
     # ------------------------------------------------------------------ utils
     def _abs_eb(self, x: np.ndarray) -> float:
@@ -646,11 +659,7 @@ class Compressor:
         else:
             rspec = dataclasses.replace(sp, eb_mode="abs", eb=float(eb_new),
                                         psnr_target=None, verify="off")
-        inner = Compressor(rspec, plan_cache=self.plan_cache)
-        buf = inner.compress(x)
-        itel = inner.last_telemetry or {}
-        self._telemetry()["fallbacks"].extend(itel.get("fallbacks") or ())
-        return buf
+        return Compressor(rspec, plan_cache=self.plan_cache).compress(x)
 
     def _verify_repair(self, x: np.ndarray, buf: bytes, *, bound: float, rel: bool) -> bytes:
         """Post-encode bound enforcement (``spec.verify`` != "off").
@@ -669,7 +678,7 @@ class Compressor:
         repairs = 0
         cur = float(bound)
         limit = bound * (1.0 + _VERIFY_SLACK) + 1e-12  # f32 rounding headroom
-        while max_err > limit:
+        while not max_err <= limit:  # NaN (a non-finite decode) is a violation too
             if repairs >= _REPAIR_POLICY.attempts or cur <= 0.0:
                 tel["verify"] = {"mode": sp.verify, "checked": checked,
                                  "max_err": max_err, "bound": bound, "repairs": repairs}
@@ -711,20 +720,12 @@ class Compressor:
         (repro.core.lossless.engine); ``"auto"`` keeps whatever residency
         the stream already has. Either way the payload bytes are identical
         (the engine's bit-identity contract), so the header carries no
-        engine field and decode never knows.
-
-        Fallback ladder: a device-engine failure (lowering, OOM, a dead
-        accelerator) pulls the stream to host and retries the numpy
-        reference path — bit-identical output, recorded in
-        ``last_telemetry`` so the degradation is observable, never silent.
+        engine field and decode never knows. An engine failure raises.
         """
         sp = self.spec
         is_dev = pipelines._is_jax(seq)
         if sp.engine == "device" and not is_dev:
-            try:
-                seq = jnp.asarray(np.ascontiguousarray(seq, np.uint8))
-            except Exception as e:  # device placement itself failed
-                self._record_fallback("encode", "device", "numpy", e)
+            seq = jnp.asarray(np.ascontiguousarray(seq, np.uint8))
         elif sp.engine == "numpy" and is_dev:
             seq = np.asarray(seq)
         fixed = sp.pipeline if sp.pipeline != "auto" else pipeline_override
@@ -733,13 +734,6 @@ class Compressor:
             if sp.pipeline == "auto":
                 hdr["pcached"] = True  # plan-cache replay, not a spec-fixed pipeline
             self._telemetry()["pipeline"] = fixed
-            try:
-                return pipelines.encode(seq, fixed), hdr
-            except Exception as e:
-                if not pipelines._is_jax(seq):
-                    raise  # host reference path: a real error, not a device fault
-                self._record_fallback("encode", "device", "numpy", e)
-                seq = np.asarray(seq)
             return pipelines.encode(seq, fixed), hdr
         histogram = None
         if sp.backend == "pallas" and not pipelines._is_jax(seq):
@@ -749,21 +743,9 @@ class Compressor:
 
             interpret = jax.devices()[0].platform != "tpu"
             histogram = lambda d: histogram256_pallas(d, interpret=interpret)  # noqa: E731
-        try:
-            payload, record = orchestrate.encode_auto(
-                seq, candidates=sp.pipeline_candidates, histogram=histogram
-            )
-        except Exception as e:
-            if pipelines._is_jax(seq):
-                self._record_fallback("encode", "device", "numpy", e)
-                seq, histogram = np.asarray(seq), None
-            elif histogram is not None:  # pallas histogram hook failed
-                self._record_fallback("histogram", "pallas", "numpy", e)
-                histogram = None
-            else:
-                raise
-            payload, record = orchestrate.encode_auto(seq, candidates=sp.pipeline_candidates,
-                                                      histogram=histogram)
+        payload, record = orchestrate.encode_auto(
+            seq, candidates=sp.pipeline_candidates, histogram=histogram
+        )
         self._telemetry()["pipeline"] = record["pipeline"]
         return payload, {"pipeline": record["pipeline"], "pchoice": record}
 
@@ -834,20 +816,13 @@ class Compressor:
 
         Returns backend-native arrays (device for the jax backend) — the
         host path converts, the device-engine path keeps them resident.
-
-        A Pallas lowering/runtime failure falls back to the jax engine —
-        both backends quantize with the same arithmetic, so the output is
-        identical; the fallback lands in ``last_telemetry``.
         """
         if self.spec.backend == "pallas" and ndim == 3:
-            try:
-                from repro.kernels.interp3d import compress_blocks_pallas
+            from repro.kernels.interp3d import compress_blocks_pallas
 
-                codes_b, outl_b, _ = compress_blocks_pallas(blocks, 2.0 * eb_abs, steps, stride)
-                return self._maybe_fault_codes(codes_b), outl_b
-            except Exception as e:
-                self._record_fallback("predictor", "pallas", "jax", e)
-        codes_b, outl_b, _ = compress_blocks(jnp.asarray(blocks), jnp.float32(2.0 * eb_abs), steps, stride)
+            codes_b, outl_b, _ = compress_blocks_pallas(blocks, 2.0 * eb_abs, steps, stride)
+            return self._maybe_fault_codes(codes_b), outl_b
+        codes_b, outl_b, _ = compress_blocks(jnp.asarray(blocks), *quant_steps(eb_abs), steps, stride)
         return self._maybe_fault_codes(codes_b), outl_b
 
     @staticmethod
@@ -899,14 +874,9 @@ class Compressor:
         """
         sp = self.spec
         if pipelines._is_jax(cgrid):
-            try:
-                from .reorder import reorder_codes_batch_device
+            from .reorder import reorder_codes_batch_device
 
-                seq = reorder_codes_batch_device(cgrid, stride, sp.reorder)
-            except Exception as e:  # device reorder failed: host twin, same bytes
-                self._record_fallback("reorder", "device", "numpy", e)
-                cgrid = np.asarray(cgrid)
-                seq = reorder_codes_batch(cgrid, stride, sp.reorder)
+            seq = reorder_codes_batch_device(cgrid, stride, sp.reorder)
         else:
             seq = reorder_codes_batch(cgrid, stride, sp.reorder)
         payload, penc = self._encode_codes(seq, pipeline_override=pipeline_override)
@@ -920,6 +890,7 @@ class Compressor:
             schemes=list(schemes),
             reorder=bool(sp.reorder),
             n_outliers=int(oi.size),
+            arith=ARITH,
             **penc,
         )
         # No separate plan blob: the plan IS (anchor_stride, splines, schemes),
@@ -976,35 +947,24 @@ class Compressor:
             stride, splines, schemes = self._tune_interp(blocks, eb_abs, batch, padded_shapes)
         steps = build_steps(ndim, blk.BLOCK, levels_for_stride(stride), splines, schemes)
         codes_b, outl_b = self._run_predictor(blocks, eb_abs, steps, stride, ndim)
-        buf = None
+        anc = blk.anchor_grid_batch(padded, stride)
         if sp.engine == "device":
             # fused tail: codes stay device-resident through block scatter,
             # level reorder, and the encoding engine (inside _pack_interp);
             # outliers come from the code==0 <=> outlier invariant the
             # sharded path already relies on — no outlier grid crosses over
-            try:
-                cgrid = blk.scatter_blocks_batch_jnp(jnp.asarray(codes_b), batch,
-                                                     padded_shapes, blk.ANCHOR_STRIDE)
-                anc = blk.anchor_grid_batch(padded, stride)
-                oi = np.asarray(jnp.flatnonzero(cgrid.reshape(-1) == 0)).astype(np.int64)
-                ov = padded.reshape(-1)[oi]
-                buf = self._pack_interp(base_hdr, cgrid=cgrid, anc=anc, oi=oi, ov=ov,
-                                        stride=stride, splines=splines, schemes=schemes,
-                                        pipeline_override=pipe_override)
-            except Exception as e:
-                # device tail failed (lowering/OOM/dead device): replay the
-                # numpy reference tail below — bit-identical container
-                self._record_fallback("pack", "device", "numpy", e)
-        if buf is None:
+            cgrid = blk.scatter_blocks_batch_jnp(jnp.asarray(codes_b), blk._scatter_index(padded_shapes),
+                                                 batch, padded_shapes)
+            oi = np.asarray(jnp.flatnonzero(cgrid.reshape(-1) == 0)).astype(np.int64)
+        else:
             codes_b, outl_b = np.asarray(codes_b), np.asarray(outl_b)
             cgrid = blk.scatter_blocks_batch(codes_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
             ogrid = blk.scatter_blocks_batch(outl_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
-            anc = blk.anchor_grid_batch(padded, stride)
             oi = np.flatnonzero(ogrid.reshape(-1)).astype(np.int64)  # already batch-global
-            ov = padded.reshape(-1)[oi]
-            buf = self._pack_interp(base_hdr, cgrid=cgrid, anc=anc, oi=oi, ov=ov,
-                                    stride=stride, splines=splines, schemes=schemes,
-                                    pipeline_override=pipe_override)
+        ov = padded.reshape(-1)[oi]
+        buf = self._pack_interp(base_hdr, cgrid=cgrid, anc=anc, oi=oi, ov=ov,
+                                stride=stride, splines=splines, schemes=schemes,
+                                pipeline_override=pipe_override)
         if ckey is not None and cached is None:
             plan = self.last_plan if sp.predictor == "auto" else None
             self.plan_cache.put(ckey, {
@@ -1041,8 +1001,8 @@ class Compressor:
         ``|x'/x - 1| = |exp(y' - y) - 1| <= eb``; signs and exact zeros
         ride packed bitmaps and reconstruct exactly. ``y`` takes the
         existing quantize -> orchestrate -> engine path unchanged (the
-        inner payload is a complete v2 container), so plan caching,
-        engine selection, and the fallback ladder all apply. The margin
+        inner payload is a complete v2 container), so plan caching
+        and engine selection apply. The margin
         subtracted from ``log1p(eb)`` covers the float32 storage of the
         log field and the f64->f32 rounding of the reconstruction, making
         the bound hold in delivered float32 arithmetic, not just in exact
@@ -1075,7 +1035,6 @@ class Compressor:
         ibuf = inner.compress(y.reshape(x.shape))
         itel = inner.last_telemetry or {}
         tel = self._telemetry()
-        tel["fallbacks"].extend(itel.get("fallbacks") or ())
         for k in ("pipeline", "plan_cache"):
             if k in itel:
                 tel[k] = itel[k]
@@ -1199,8 +1158,7 @@ class Compressor:
         restore/anchor-placement/reconstruction, so the field never
         bounces through host memory. Bytes-for-bytes the result matches
         the numpy path (the engine bit-identity contract); a device decode
-        failure falls back to the numpy path and is recorded on
-        ``last_telemetry["fallbacks"]``. Each call also records
+        failure raises. Each call also records
         ``last_telemetry["decode"]`` (engine, out, seconds, bytes, MB/s).
 
         ``on_error`` — degraded-mode decode of damaged containers:
@@ -1310,28 +1268,27 @@ class Compressor:
         anc = np.frombuffer(sections[1], np.float32)
         oi = np.frombuffer(sections[2], np.int64)
         ov = np.frombuffer(sections[3], np.float32)
-        if device:
+        arith = header.get("arith")
+        if arith not in (None, ARITH):
+            raise ValueError(f"unknown predictor arithmetic {arith!r}; this build replays {ARITH}")
+        # arith containers were quantized with quant_steps' step; older ones with 2 * eb_abs
+        twoeb = np.float32(2.0 * eb_abs) if arith is None else quant_steps(eb_abs)[0]
+        if device and arith is not None:
             # device-resident tail: codes decode through the stage twins and
             # every hop to the reconstructed field is a jnp gather — same
-            # bytes as the numpy path below (bit-identity contract)
-            try:
-                seq = pipelines.decode(sections[0], device=True)
-                cgrid = restore_codes_batch_device(seq, batch, padded_shapes, fill=128,
-                                                   stride=stride, reorder=header.get("reorder", True))
-                agrid = blk.place_anchors_batch_jnp(
-                    padded_shapes, jnp.asarray(anc).reshape((batch,) + anc_shape), stride)
-                ovflat = jnp.zeros(batch * psize, jnp.float32)
-                if oi.size:  # outlier indices are batch-global and unique
-                    ovflat = ovflat.at[jnp.asarray(oi)].set(jnp.asarray(ov))
-                ovgrid = ovflat.reshape((batch,) + padded_shapes)
-                cb = blk.gather_blocks_batch_jnp(cgrid, blk.ANCHOR_STRIDE)
-                ab = blk.gather_blocks_batch_jnp(agrid, blk.ANCHOR_STRIDE)
-                vb = blk.gather_blocks_batch_jnp(ovgrid, blk.ANCHOR_STRIDE)
-                recon_b = decompress_blocks(cb, ab, vb, jnp.float32(2.0 * eb_abs), steps, stride)
-                out = blk.scatter_blocks_batch_jnp(recon_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
-                return out[sl].reshape(shape)
-            except Exception as e:
-                self._record_fallback("decode", "device", "numpy", e)
+            # bytes as the numpy path below (bit-identity contract).
+            # Containers without "arith" take the host path, whose replay
+            # must run on XLA:CPU.
+            seq = pipelines.decode(sections[0], device=True)
+            ovflat = jnp.zeros(batch * psize, jnp.float32)
+            if oi.size:  # outlier indices are batch-global and unique
+                ovflat = ovflat.at[jnp.asarray(oi)].set(jnp.asarray(ov))
+            reorder = bool(header.get("reorder", True))
+            out = _reconstruct_device(
+                seq, jnp.asarray(anc).reshape((batch,) + anc_shape), ovflat, twoeb,
+                _restore_gather(padded_shapes, stride, reorder), blk._anchor_index(padded_shapes, stride),
+                blk._scatter_index(padded_shapes), batch=batch, padded=padded_shapes, stride=stride, steps=steps)
+            return out[sl].reshape(shape)
         seq = pipelines.decode(sections[0])
         cgrid = restore_codes_batch(seq, batch, padded_shapes, fill=128, dtype=np.uint8,
                                     stride=stride, reorder=header.get("reorder", True))
@@ -1342,7 +1299,14 @@ class Compressor:
         cb = blk.gather_blocks_batch(cgrid, blk.ANCHOR_STRIDE)
         ab = blk.gather_blocks_batch(agrid, blk.ANCHOR_STRIDE)
         vb = blk.gather_blocks_batch(ovgrid, blk.ANCHOR_STRIDE)
-        recon_b = np.asarray(decompress_blocks(jnp.asarray(cb), jnp.asarray(ab), jnp.asarray(vb), jnp.float32(2.0 * eb_abs), steps, stride))
+        if arith is None:
+            # written by the matmul-form encoder on XLA:CPU: replayed there,
+            # so such archives decode to the same floats on any host
+            with jax.default_device(jax.devices("cpu")[0]):
+                recon_b = np.asarray(decompress_blocks_matmul(cb, ab, vb, twoeb, steps, stride))
+        else:
+            recon_b = np.asarray(decompress_blocks(jnp.asarray(cb), jnp.asarray(ab), jnp.asarray(vb),
+                                                   twoeb, steps, stride))
         out = blk.scatter_blocks_batch(recon_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
         return out[sl].reshape(shape)
 
@@ -1405,7 +1369,7 @@ class Compressor:
             raise ValueError("frames= selected no frames; pass at least one index (or None for all)")
         parts, mask = [], []
         # per-frame decompress() calls share this call's telemetry dict
-        # (fallbacks accumulate) instead of resetting it frame by frame
+        # instead of resetting it frame by frame
         hold, self._telemetry_hold = self._telemetry_hold, True
         try:
             for i in idx:
@@ -1446,17 +1410,14 @@ class Compressor:
         oi = np.frombuffer(sections[1], np.int64)
         ov = np.frombuffer(sections[2], np.int32)
         if device:
-            try:
-                seq = pipelines.decode(sections[0], device=True)
-                codes = seq.reshape((batch,) + spatial)
-                ofull = jnp.zeros(codes.size, jnp.int32)
-                if oi.size:
-                    ofull = ofull.at[jnp.asarray(oi)].set(jnp.asarray(ov))
-                out = lor.lorenzo_decode(codes, ofull.reshape(codes.shape),
-                                         jnp.float32(2.0 * header["eb_abs"]), len(spatial))
-                return out.reshape(shape)
-            except Exception as e:
-                self._record_fallback("decode", "device", "numpy", e)
+            seq = pipelines.decode(sections[0], device=True)
+            codes = seq.reshape((batch,) + spatial)
+            ofull = jnp.zeros(codes.size, jnp.int32)
+            if oi.size:
+                ofull = ofull.at[jnp.asarray(oi)].set(jnp.asarray(ov))
+            out = lor.lorenzo_decode(codes, ofull.reshape(codes.shape),
+                                     jnp.float32(2.0 * header["eb_abs"]), len(spatial))
+            return out.reshape(shape)
         seq = pipelines.decode(sections[0])
         codes = seq.reshape((batch,) + spatial)
         ofull = np.zeros(codes.size, np.int32)

@@ -29,9 +29,11 @@ replicate the single-host path, so ``shard_compress(x)[i]`` ==
 single-host readers (or vice versa) round-trips.
 
 ``chunk_compress`` is the host-sequential twin (same v3 output, no mesh
-needed) used as the fallback — non-divisible axes, 1-device hosts,
-predictors without a device path — and as the checkpoint codec's
-streaming producer. ``shard_decompress`` reads any v3 chunk stream,
+needed). ``shard_compress`` routes to it, by an up-front choice recorded
+in ``last_telemetry["shard"]``, for non-divisible axes, 1-device meshes,
+predictors without a device path and fields holding NaN/Inf; it is also
+the checkpoint codec's streaming producer. A failure of the device passes
+raises. ``shard_decompress`` reads any v3 chunk stream,
 optionally with a thread pool (frames decode independently, so decode
 parallelism is embarrassing).
 """
@@ -50,7 +52,7 @@ from . import frames
 from .autotune import levels_for_stride, legacy_sample_indices, plan_sample_indices
 from . import compressor as _compressor_mod
 from .compressor import Compressor, CompressorSpec, _sections_pack
-from .predictor import compress_blocks
+from .predictor import compress_blocks, quant_steps
 from .stencils import build_steps
 
 _AXIS = "shards"
@@ -102,7 +104,7 @@ def _fold_chunk(chunk):
     return chunk.reshape((batch,) + spatial), spatial
 
 
-def _predict_codes(blocks, twoeb, steps, stride: int, ndim: int, backend: str):
+def _predict_codes(blocks, twoeb, inv2eb, steps, stride: int, ndim: int, backend: str):
     """Fused predict+quantize on the device shard (jax or Pallas kernel)."""
     if backend == "pallas" and ndim == 3:
         from repro.kernels.interp3d.interp3d import LANES, interp3d_compress
@@ -113,9 +115,9 @@ def _predict_codes(blocks, twoeb, steps, stride: int, ndim: int, backend: str):
             blocks = jnp.concatenate([blocks, jnp.zeros((lane_pad,) + blocks.shape[1:], blocks.dtype)], 0)
         bt = jnp.moveaxis(blocks, 0, -1)  # (B,B,B,nb') — block axis on lanes
         interpret = jax.default_backend() != "tpu"
-        codes, _, _ = interp3d_compress(bt, twoeb, steps, stride, interpret)
+        codes, _ = interp3d_compress(bt, twoeb, inv2eb, steps, stride, interpret)
         return jnp.moveaxis(codes, -1, 0)[:nbk]
-    codes, _, _ = compress_blocks(blocks, twoeb, steps, stride)
+    codes, _, _ = compress_blocks(blocks, twoeb, inv2eb, steps, stride)
     return codes
 
 
@@ -137,7 +139,7 @@ def _gather_flat(dev_arr, oi: np.ndarray) -> np.ndarray:
     return np.asarray(vals, np.float32)
 
 
-# ------------------------------------------------------------ host fallback
+# ------------------------------------------------------------ host twin
 def chunk_compress(x, *, axis: int = 0, n_chunks: int | None = None,
                    spec: CompressorSpec | None = None, compressor: Compressor | None = None,
                    out=None, sync: bool = False, **kw) -> bytes | int:
@@ -161,7 +163,7 @@ def chunk_compress(x, *, axis: int = 0, n_chunks: int | None = None,
     sizes = np.diff(bounds)
     sink = out if out is not None else io.BytesIO()
     hold, comp._telemetry_hold = comp._telemetry_hold, True
-    if not hold:  # a holding caller (shard fallback) keeps its records
+    if not hold:  # a holding caller (shard_compress) keeps its records
         comp.last_telemetry = None
     try:
         with frames.FrameWriter(sink, _chunk_header(x.shape, axis, sizes, comp.spec), sync=sync) as w:
@@ -184,15 +186,13 @@ def shard_compress(x, mesh: Mesh | None = None, *, axis: int = 0,
     ``x``: array (numpy or jax, possibly already device-sharded) or a
     pytree of arrays — a pytree maps to a same-structure pytree of v3
     containers. ``mesh``: a 1-D mesh; defaults to all local devices.
-    Chunks = equal splits of ``x.shape[axis]`` across the mesh. Falls back
-    to :func:`chunk_compress` (identical container format) when the axis
-    doesn't split evenly, the mesh is a single device, or the spec's
-    predictor has no device path — and, new with the resilience layer,
-    when the device passes themselves *fail* (a lowering error, a dead
-    mesh) before any frame was emitted: the host path re-runs the whole
-    field and the fallback is recorded in the compressor's
-    ``last_telemetry``, so a transient accelerator fault degrades
-    throughput instead of killing the save. ``out``: optional file-like
+    Chunks = equal splits of ``x.shape[axis]`` across the mesh. Runs
+    :func:`chunk_compress` (identical container format) instead when the
+    axis doesn't split evenly, the mesh is a single device, the spec's
+    predictor has no device path, or the field holds NaN/Inf (the device
+    passes have no nfsafe stage). ``last_telemetry["shard"]`` records the
+    path taken (``"shard_map"`` or ``"chunk_compress"``, with the reason).
+    A failure of the device passes raises. ``out``: optional file-like
     sink, frames stream to it as encoded (returns the frame count).
     ``sync=True`` adds per-frame sync markers (see
     :mod:`repro.core.frames`).
@@ -220,53 +220,50 @@ def shard_compress(x, mesh: Mesh | None = None, *, axis: int = 0,
         raise ValueError(f"shard_compress needs a 1-D mesh, got axes {mesh.axis_names}")
     ndev = int(np.prod(mesh.devices.shape))
     n = int(x.shape[axis])
-    if ndev == 1 or n % ndev != 0 or sp.predictor not in ("interp", "auto"):
-        return chunk_compress(np.asarray(x), axis=axis, n_chunks=min(n, max(ndev, 1)),
-                              compressor=comp, out=out, sync=sync)
-    k = n // ndev
-    chunk_shape = tuple(k if d == axis else s for d, s in enumerate(x.shape))
-    header = _chunk_header(x.shape, axis, [k] * ndev, sp)
     hold, comp._telemetry_hold = comp._telemetry_hold, True
     if not hold:
         comp.last_telemetry = None
+
+    def host_twin(reason: str, n_chunks: int):
+        comp._telemetry()["shard"] = {"path": "chunk_compress", "reason": reason, "ndev": ndev}
+        return chunk_compress(np.asarray(x), axis=axis, n_chunks=n_chunks,
+                              compressor=comp, out=out, sync=sync)
+
     try:
-        # _shard_compress_frames is a generator: the device passes run up
-        # front, but each chunk's host tail (scatter/orchestrate/encode)
-        # yields its frame as soon as it is packed, so sink writeback
-        # overlaps the next chunk's encode. Pulling the first frame before
-        # opening the writer keeps the engine-failure fallback clean: if
-        # the device passes die, nothing was written yet and the whole
-        # field replays through the host path (identical container).
-        gen = _shard_compress_frames(x, mesh, axis, ndev, k, chunk_shape, comp)
-        try:
-            first = next(gen, None)
-        except Exception as e:
-            comp._record_fallback("shard", "shard_map", "chunk_compress", e)
-            return chunk_compress(np.asarray(x), axis=axis, n_chunks=ndev,
-                                  compressor=comp, out=out, sync=sync)
+        if ndev == 1:
+            return host_twin("one device", 1)
+        if n % ndev:
+            return host_twin(f"axis {n} not divisible by {ndev}", min(n, ndev))
+        if sp.predictor not in ("interp", "auto"):
+            return host_twin(f"predictor {sp.predictor!r} has no device path", min(n, ndev))
+        k = n // ndev
+        chunk_shape = tuple(k if d == axis else s for d, s in enumerate(x.shape))
+        xd, mn, mx, samples = _shard_pass_a(x, mesh, axis, chunk_shape, comp)
+        # non-finite ingest: NaN/Inf anywhere in a chunk poisons its min/max
+        # (jnp reductions propagate), so this one check covers the whole
+        # field; chunk_compress's per-chunk Compressor.compress runs the
+        # nfsafe canonicalization (bitmap + fill)
+        if not (np.isfinite(mn).all() and np.isfinite(mx).all()):
+            return host_twin("non-finite values", ndev)
+        comp._telemetry()["shard"] = {"path": "shard_map", "ndev": ndev}
+        # _shard_compress_frames is a generator: each chunk's host tail
+        # (scatter/orchestrate/encode) yields its frame as soon as it is
+        # packed, so sink writeback overlaps the next chunk's encode.
+        gen = _shard_compress_frames(xd, mn, mx, samples, mesh, axis, ndev, k, chunk_shape, comp)
         sink = out if out is not None else io.BytesIO()
-        with frames.FrameWriter(sink, header, sync=sync) as w:
-            if first is not None:
-                w.write_frame(first)
-                for fr in gen:
-                    w.write_frame(fr)
+        with frames.FrameWriter(sink, _chunk_header(x.shape, axis, [k] * ndev, sp),
+                                sync=sync) as w:
+            for fr in gen:
+                w.write_frame(fr)
         nf = w.close()
     finally:
         comp._telemetry_hold = hold
     return nf if out is not None else sink.getvalue()
 
 
-def _shard_compress_frames(x, mesh, axis, ndev, k, chunk_shape, comp):
-    sp = comp.spec
-    aname = mesh.axis_names[0]
-    spec_sharded = P(*(aname if d == axis else None for d in range(len(chunk_shape))))
-    sharding = NamedSharding(mesh, spec_sharded)
-    xd = jax.device_put(jnp.asarray(x, jnp.float32), sharding)
-    scalar_spec = P(aname)
-    scalar_sharding = NamedSharding(mesh, scalar_spec)
-    from repro.runtime.partitioning import shard_map
-
-    # static per-chunk geometry (chunks are uniform)
+def _chunk_geometry(chunk_shape, sp):
+    """Static per-chunk geometry (chunks are uniform): (nd, cb,
+    padded_shapes, nblocks, tune, sample_idx)."""
     nd = min(len(chunk_shape), 3)
     spatial = chunk_shape[len(chunk_shape) - nd :]
     cb = int(np.prod(chunk_shape[: len(chunk_shape) - nd], dtype=np.int64)) if len(chunk_shape) > nd else 1
@@ -274,8 +271,21 @@ def _shard_compress_frames(x, mesh, axis, ndev, k, chunk_shape, comp):
     nblocks = cb * int(np.prod(blk.block_grid(padded_shapes, blk.ANCHOR_STRIDE)))
     tune = sp.predictor == "auto" or (sp.predictor == "interp" and sp.autotune)
     sample_idx = (plan_sample_indices if sp.predictor == "auto" else legacy_sample_indices)(nblocks)
+    return nd, cb, padded_shapes, nblocks, tune, sample_idx
 
-    # ---- pass A: per-chunk range (rel eb) + shard-side tuning sample
+
+def _shard_pass_a(x, mesh, axis, chunk_shape, comp):
+    """Place ``x`` on the mesh and run pass A: per-chunk min/max (the rel
+    bound) and the shard-side tuning sample. Returns (xd, mn, mx, samples)
+    with the last three on host."""
+    from repro.runtime.partitioning import shard_map
+
+    aname = mesh.axis_names[0]
+    spec_sharded = P(*(aname if d == axis else None for d in range(len(chunk_shape))))
+    x = x.astype(jnp.float32) if isinstance(x, jax.Array) else np.asarray(x, np.float32)
+    xd = jax.device_put(x, NamedSharding(mesh, spec_sharded))  # host shards go straight to their device
+    _, _, _, _, tune, sample_idx = _chunk_geometry(chunk_shape, comp.spec)
+
     def body_a(chunk):
         xb, _ = _fold_chunk(chunk)
         mn = jnp.min(xb).reshape(1) if xb.size else jnp.zeros(1)
@@ -285,20 +295,20 @@ def _shard_compress_frames(x, mesh, axis, ndev, k, chunk_shape, comp):
         sample = blocks[jnp.asarray(sample_idx)] if tune else jnp.zeros((1,) + blocks.shape[1:])
         return mn, mx, sample
 
-    fa = shard_map(body_a, mesh, in_specs=(spec_sharded,), out_specs=(scalar_spec,) * 3)
+    fa = shard_map(body_a, mesh, in_specs=(spec_sharded,), out_specs=(P(aname),) * 3)
     mn, mx, samples = jax.jit(fa)(xd)
-    mn, mx = np.asarray(mn), np.asarray(mx)
-    # non-finite ingest: NaN/Inf anywhere in a chunk poisons its min/max
-    # (jnp reductions propagate), so this one check covers the whole
-    # field. Raising before the first yield routes the caller onto
-    # chunk_compress, whose per-chunk Compressor.compress runs the
-    # nfsafe canonicalization (bitmap + fill) — recorded as a shard
-    # fallback in last_telemetry, never silent.
-    if not (np.isfinite(mn).all() and np.isfinite(mx).all()):
-        raise ValueError(
-            "non-finite values (NaN/Inf) in the field; the device shard path has no "
-            "nfsafe pass — falling back to chunk_compress for canonicalized ingest")
-    samples = np.asarray(samples)
+    return xd, np.asarray(mn), np.asarray(mx), np.asarray(samples)
+
+
+def _shard_compress_frames(xd, mn, mx, samples, mesh, axis, ndev, k, chunk_shape, comp):
+    sp = comp.spec
+    aname = mesh.axis_names[0]
+    spec_sharded = P(*(aname if d == axis else None for d in range(len(chunk_shape))))
+    scalar_spec = P(aname)
+    scalar_sharding = NamedSharding(mesh, scalar_spec)
+    from repro.runtime.partitioning import shard_map
+
+    nd, cb, padded_shapes, nblocks, tune, sample_idx = _chunk_geometry(chunk_shape, sp)
     ns = sample_idx.size if tune else 1
 
     # ---- per-chunk eb + tuning (host; the sample is all it needs)
@@ -340,22 +350,20 @@ def _shard_compress_frames(x, mesh, axis, ndev, k, chunk_shape, comp):
     padded_shards: dict[int, object] = {}
     for (stride, splines, schemes), members in groups.items():
         steps = build_steps(nd, blk.BLOCK, levels_for_stride(stride), splines, schemes)
-        twoeb = np.ones(ndev, np.float32)
-        for i in members:
-            twoeb[i] = np.float32(2.0 * eb_abs[i])
+        twoeb, inv2eb = quant_steps([eb_abs[i] if i in members else 0.5 for i in range(ndev)])
 
-        def body_b(chunk, t2):
+        def body_b(chunk, t2, i2):
             xb, _ = _fold_chunk(chunk)
             padded = _pad_field_batch_jnp(xb, blk.ANCHOR_STRIDE)
             blocks = _gather_blocks_jnp(padded, blk.ANCHOR_STRIDE)
-            codes = _predict_codes(blocks, t2[0], steps, stride, nd, sp.backend)
+            codes = _predict_codes(blocks, t2[0], i2[0], steps, stride, nd, sp.backend)
             anc_sl = (slice(None),) + tuple(slice(None, None, stride) for _ in range(nd))
             return codes.astype(jnp.uint8), padded[anc_sl], padded
 
-        fb = shard_map(body_b, mesh, in_specs=(spec_sharded, scalar_spec),
+        fb = shard_map(body_b, mesh, in_specs=(spec_sharded, scalar_spec, scalar_spec),
                        out_specs=(scalar_spec,) * 3)
-        td = jax.device_put(jnp.asarray(twoeb), scalar_sharding)
-        codes_g, anc_g, padded_g = jax.jit(fb)(xd, td)
+        td, id_ = (jax.device_put(jnp.asarray(a), scalar_sharding) for a in (twoeb, inv2eb))
+        codes_g, anc_g, padded_g = jax.jit(fb)(xd, td, id_)
         anc_host = np.asarray(anc_g)
         pslices = _shard_slices(padded_g)
         per_anc = anc_host.shape[0] // ndev
@@ -390,8 +398,8 @@ def _shard_compress_frames(x, mesh, axis, ndev, k, chunk_shape, comp):
             continue
         stride, splines, schemes = tuned[i]
         if use_dev:
-            cgrid = blk.scatter_blocks_batch_jnp(jnp.asarray(codes_dev[i]), cb,
-                                                 padded_shapes, blk.ANCHOR_STRIDE)
+            cgrid = blk.scatter_blocks_batch_jnp(jnp.asarray(codes_dev[i]), blk._scatter_index(padded_shapes),
+                                                 cb, padded_shapes)
             if _compressor_mod._CODE_FAULT is not None:
                 # test-only encoder-fault hook (see testing.faults): worth a
                 # device round trip only when armed
@@ -480,12 +488,6 @@ def shard_decompress(buf, frames_sel=None, *, workers: int | None = None,
 
     from .errors import ContainerError
 
-    # per-call telemetry is thread-local: each worker's decompress records
-    # into its own thread state, so worker-side fallbacks are collected
-    # explicitly and merged into the caller's record after the join
-    # (list.append/extend are atomic under the GIL — no lock needed)
-    worker_fallbacks: list = []
-
     def _one(i: int):
         p = payloads.get(i)
         if p is None:
@@ -500,10 +502,6 @@ def shard_decompress(buf, frames_sel=None, *, workers: int | None = None,
             report.add("decode", -1, index=i, detail=repr(e))
             report.frames_damaged += 1
             return None
-        finally:
-            tel = comp.last_telemetry
-            if tel and tel.get("fallbacks"):
-                worker_fallbacks.extend(tel["fallbacks"])
 
     hold, comp._telemetry_hold = comp._telemetry_hold, True
     if not hold:
@@ -513,8 +511,6 @@ def shard_decompress(buf, frames_sel=None, *, workers: int | None = None,
             raw = list(ex.map(_one, idx))
     finally:
         comp._telemetry_hold = hold
-    if worker_fallbacks:
-        comp._telemetry()["fallbacks"].extend(worker_fallbacks)
     mask = [p is not None for p in raw]
     parts = []
     for i, p in zip(idx, raw):

@@ -660,7 +660,7 @@ def bit1_encode_device(data, block: int = _BIT1_BLOCK):
     if _on_tpu():
         from repro.kernels.bitshuffle.bitshuffle import bitshuffle_pallas_raw
 
-        planes = bitshuffle_pallas_raw(arr, False, tile_blocks=1)
+        planes = bitshuffle_pallas_raw(arr, False)
     else:
         planes = _bit1_core(arr)
     return planes.reshape(-1), {"n": n, "block": int(block)}
@@ -693,7 +693,7 @@ def bit1_decode_device(payload, header: dict):
     if _on_tpu():
         from repro.kernels.bitshuffle.bitshuffle import bitunshuffle_pallas_raw
 
-        out = bitunshuffle_pallas_raw(arr, False, tile_blocks=1)
+        out = bitunshuffle_pallas_raw(arr, False)
     else:
         out = _bit1_inv_core(arr)
     return out.reshape(-1)[:n]

@@ -113,15 +113,17 @@ def _restore_gather(shape: tuple[int, ...], stride: int, reorder: bool):
     return jnp.asarray(idx), jnp.asarray(pos >= 0)
 
 
-def restore_codes_batch_device(seq, batch: int, shape: tuple[int, ...], fill, stride: int = ANCHOR_STRIDE, reorder: bool = True):
-    """Device twin of restore_codes_batch over a uint8 device sequence.
+def restore_codes_batch_device(seq, ix, batch: int, shape: tuple[int, ...], fill):
+    """Device twin of restore_codes_batch over a uint8 device sequence;
+    ``ix`` is the :func:`_restore_gather` pair of the container's shape,
+    stride and reorder flag.
 
     Returns the (batch, *shape) uint8 grids as a device array, bit-identical
     to the numpy restore (anchor positions carry ``fill``).
     """
     import jax.numpy as jnp
 
-    idx, mask = _restore_gather(tuple(int(s) for s in shape), stride, bool(reorder))
+    idx, mask = ix
     rows = jnp.take(seq.reshape(batch, -1), idx, axis=1)
     out = jnp.where(mask[None, :], rows, jnp.uint8(fill))
     return out.reshape((batch,) + tuple(shape))

@@ -2,10 +2,13 @@
 
 TPU adaptation (see DESIGN.md §3): each 1-D spline interpolation along a
 dimension is expressed as a small banded (B,B) matrix applied along that
-axis — an MXU-friendly matmul — instead of the CUDA per-thread gather.  All
-index sets are compile-time constants because the block shape (17^ndim) is
-fixed, so each (level, sub-step) becomes: up to `ndim` matmuls, a static
-blend-weight grid, and a static target mask.
+axis, instead of the CUDA per-thread gather.  All index sets are
+compile-time constants because the block shape (17^ndim) is fixed, so each
+(level, sub-step) becomes: up to `ndim` banded operators, a static
+blend-weight grid, and a static target mask. repro.core.predictor applies
+each operator as its row stencils (shifted neighbours times per-row
+coefficients), not as a matmul, so its rounding is the same on every
+backend.
 
 Splines (SZ3/QoZ family, §5.1.2):
   cubic centred  (-1, 9, 9, -1)/16          at (c-3s, c-s, c+s, c+3s)

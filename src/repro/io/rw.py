@@ -8,7 +8,7 @@ frame. Each chunk is one frame:
 
 * lossy chunks are complete v1/v2 compressor containers, so every chunk
   decodes independently through :meth:`repro.core.Compressor.decompress`
-  — plan caching, engine selection, and the fallback ladder all apply;
+  — plan caching and engine selection apply;
 * lossless chunks are zlib-deflated raw bytes behind a small serial
   header (``RAWC`` tag), byte-identical on read for *any* dtype.
 

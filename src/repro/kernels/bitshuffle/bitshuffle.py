@@ -1,8 +1,14 @@
 """Pallas TPU kernel: BIT1 bit-plane shuffle (paper §5.2.3).
 
-Per 1024-byte block, output plane p holds bit p of every byte. Bits are
-extracted with shifts/masks on int32 lanes and re-packed with a (8,)
-weight contraction — no byte-addressed scatter, so it maps onto the VPU.
+Per block of ``block`` bytes, output plane p holds bit p (MSB first) of
+every byte: payload byte ``(p, q)`` packs bit ``7-p`` of bytes
+``8q..8q+7``, MSB first. Viewing the block as (8, block/8) — row ``s``
+holding byte ``8q+s`` of every group ``q`` — both directions are the same
+8x8 bit-matrix transpose per group, ``y[r, q] = sum_s bit_{7-r}(x[s, q])
+<< (7-s)``: shifts and masks on int32 lanes, one group per lane. The
+(block/8, 8) -> (8, block/8) byte transpose around the kernel is a plain
+XLA transpose, so the kernel's tiles are (8, block/8) with block/8 a
+multiple of 128 lanes.
 """
 from __future__ import annotations
 
@@ -17,61 +23,52 @@ TILE_BLOCKS = 8  # blocks per grid step
 
 
 def _kernel(x_ref, o_ref):
-    x = x_ref[...].astype(jnp.int32)  # (T, block)
-    T, BLOCK = x.shape
-    # bit p of each byte, MSB first: (T, 8, BLOCK)
-    planes = jnp.stack([(x >> (7 - p)) & 1 for p in range(8)], axis=1)
-    # pack each plane's BLOCK bits into BLOCK/8 bytes; weights 2^(7-b) built
-    # from iota (Pallas kernels cannot capture array constants)
-    w = jnp.left_shift(jnp.int32(1), 7 - jax.lax.iota(jnp.int32, 8))
-    g = planes.reshape(T, 8, BLOCK // 8, 8)
-    packed = jnp.einsum("tpgb,b->tpg", g, w, preferred_element_type=jnp.int32)
-    o_ref[...] = packed.reshape(T, BLOCK).astype(jnp.uint8)
+    x = x_ref[...].astype(jnp.int32)  # (T, 8, G): row s = byte s of each group
+    rows = [x[:, s, :] for s in range(8)]
+    out = []
+    for r in range(8):
+        acc = None
+        for s in range(8):
+            t = ((rows[s] >> (7 - r)) & 1) << (7 - s)
+            acc = t if acc is None else acc | t
+        out.append(acc)
+    o_ref[...] = jnp.stack(out, axis=1).astype(jnp.uint8)
 
 
-def _inv_kernel(x_ref, o_ref):
-    x = x_ref[...].astype(jnp.int32)  # (T, block) plane-major payload
-    T, BLOCK = x.shape
-    # payload byte (plane p, group q) holds bit p of bytes 8q..8q+7; unpack
-    # MSB first with iota-built shifts (Pallas kernels cannot capture
-    # array constants), giving bits[t, p, i] = bit p of original byte i
-    sh = 7 - jax.lax.iota(jnp.int32, 8)
-    g = x.reshape(T, 8, BLOCK // 8)
-    bits = ((g[:, :, :, None] >> sh) & 1).reshape(T, 8, BLOCK)
-    # re-pack across planes: byte i = sum_p bits[p, i] << (7-p)
-    w = jnp.left_shift(jnp.int32(1), 7 - jax.lax.iota(jnp.int32, 8))
-    out = jnp.einsum("tpq,p->tq", bits, w, preferred_element_type=jnp.int32)
-    o_ref[...] = out.astype(jnp.uint8)
-
-
-def _pallas_apply(kernel, x, interpret: bool, tile_blocks: int):
-    n, block = x.shape
-    spec = pl.BlockSpec((tile_blocks, block), lambda i: (i, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(n // tile_blocks,),
+def _transpose8(x, interpret: bool):
+    """(n, 8, G) u8 -> per-group 8x8 bit-matrix transpose, same shape."""
+    n = x.shape[0]
+    pad = (-n) % TILE_BLOCKS
+    if pad:
+        x = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
+    spec = pl.BlockSpec((TILE_BLOCKS,) + x.shape[1:], lambda i: (i, 0, 0))
+    y = pl.pallas_call(
+        _kernel,
+        grid=(x.shape[0] // TILE_BLOCKS,),
         in_specs=[spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint8),
         interpret=interpret,
     )(x)
+    return y[:n]
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def bitshuffle_pallas_raw(x: jnp.ndarray, interpret: bool = True,
-                          tile_blocks: int = TILE_BLOCKS):
-    """x: (nblocks, block) u8 with nblocks % tile_blocks == 0.
+@functools.partial(jax.jit, static_argnums=(1,))
+def bitshuffle_pallas_raw(x: jnp.ndarray, interpret: bool = True):
+    """x: (nblocks, block) u8, block % 1024 == 0 -> plane-major payload.
 
-    The block size is taken from ``x.shape[1]``; the kernel body is shape-
-    generic, so the device encoding engine reuses it for the host encoder's
-    8192-byte-block layout (``tile_blocks=1``) while the default 1024-byte
-    call sites keep their 8-block tiles.
+    The block size is taken from ``x.shape[1]``, so the device encoding
+    engine runs the host encoder's 8192-byte-block layout through the same
+    kernel as the default 1024-byte call sites.
     """
-    return _pallas_apply(_kernel, x, interpret, tile_blocks)
+    n, block = x.shape
+    xt = jnp.swapaxes(x.reshape(n, block // 8, 8), 1, 2)  # (n, 8, G)
+    return _transpose8(xt, interpret).reshape(n, block)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def bitunshuffle_pallas_raw(x: jnp.ndarray, interpret: bool = True,
-                            tile_blocks: int = TILE_BLOCKS):
-    """Inverse of :func:`bitshuffle_pallas_raw` (same tiling contract)."""
-    return _pallas_apply(_inv_kernel, x, interpret, tile_blocks)
+@functools.partial(jax.jit, static_argnums=(1,))
+def bitunshuffle_pallas_raw(x: jnp.ndarray, interpret: bool = True):
+    """Inverse of :func:`bitshuffle_pallas_raw` (same layout contract)."""
+    n, block = x.shape
+    y = _transpose8(x.reshape(n, 8, block // 8), interpret)
+    return jnp.swapaxes(y, 1, 2).reshape(n, block)

@@ -2,14 +2,17 @@
 
 This is the ``backend="pallas"`` entry point used by
 ``repro.core.compressor.Compressor``: interpret mode is auto-selected (the
-kernel interprets on CPU/GPU hosts and compiles on TPU), so the same spec
-flag works across environments.
+kernel interprets on CPU/GPU hosts and compiles on TPU). The compiled
+kernel runs at 512^3 on a TPU v5e in ``chip_smoke.py`` phase c, where its
+container equals the jax predictor's byte for byte.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.predictor import quant_steps
 
 from .interp3d import LANES, interp3d_compress
 
@@ -31,9 +34,10 @@ def compress_blocks_pallas(blocks: np.ndarray, twoeb: float, steps, anchor_every
     if pad:
         blocks = np.concatenate([blocks, np.zeros((pad,) + blocks.shape[1:], blocks.dtype)], 0)
     bt = jnp.asarray(np.moveaxis(blocks, 0, -1))  # (B,B,B,nb')
-    codes, outl, recon = interp3d_compress(bt, jnp.float32(twoeb), steps, anchor_every, interpret)
+    codes, recon = interp3d_compress(bt, *quant_steps(0.5 * twoeb), steps, anchor_every, interpret)
     mv = lambda a: np.moveaxis(np.asarray(a), -1, 0)[:nb]
-    return mv(codes), mv(outl).astype(bool), mv(recon)
+    codes = mv(codes)
+    return codes, codes == 0, mv(recon)
 
 
 def compress_blocks_pallas_plan(blocks: np.ndarray, twoeb: float, plan, interpret: bool | None = None):

@@ -57,9 +57,9 @@ socket, so queued and rejected requests never buffer bytes.
 
 Zero-payload control ops (stats/ping/health/shutdown) bypass admission
 and run on the connection thread, so observability stays responsive
-under load. Per-request faults (bad spec, damaged container, engine
-failure past the compressor's own fallback ladder) become typed error
-responses; the worker pool and the other streams are untouched.
+under load. Per-request faults (bad spec, damaged container, a device
+or kernel failure) become typed error responses; the worker pool and the
+other streams are untouched.
 
 Survivability — the daemon also *exits* cleanly and refuses to wedge:
 
@@ -513,10 +513,11 @@ class CompressdServer:
                         continue
                     except (ConnectionError, OSError):
                         break
-                # bytes are reserved from here; released on the normal path
-                # below, or by the done-callback when a deadline strands the
+                # bytes are reserved from here; released once the response is
+                # sent, or by the done-callback when a deadline strands the
                 # worker (releasing early would lie to admission control —
-                # the straggler still holds memory until it finishes)
+                # the straggler still holds memory until it finishes — and
+                # would let a drain close the socket before the reply)
                 released = False
                 try:
                     payload = _recv_exact(sock, plen)
@@ -540,10 +541,11 @@ class CompressdServer:
                         rh, rp = self._error_response(e), b""
                     except Exception as e:  # degrade, never die
                         rh, rp = self._error_response(e), b""
+                    sent = self._respond(sock, rh, rp)
                 finally:
                     if not released:
                         self._release(plen)
-                if not self._respond(sock, rh, rp):
+                if not sent:
                     break
         finally:
             with self._conn_lock:
@@ -690,7 +692,7 @@ class CompressdServer:
             "ok": True, "cr": len(payload) / max(len(buf), 1), "seconds": dt,
             "mbps": len(payload) / dt / 1e6 if dt > 0 else 0.0,
             "plan_cache": cache_state, "pipeline": tel.get("pipeline"),
-            "fallbacks": len(tel.get("fallbacks") or ()),
+            "repairs": tel.get("verify", {}).get("repairs", 0),
         }
         return info, buf
 
@@ -953,6 +955,9 @@ def main(argv=None) -> int:
                     help="SIGTERM drain budget for in-flight requests "
                          "(default REPRO_COMPRESSD_DRAIN_S or 30)")
     args = ap.parse_args(argv)
+    from repro.launch.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     server = CompressdServer(
         args.addr,
         workers=args.workers,

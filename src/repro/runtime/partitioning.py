@@ -37,31 +37,15 @@ _STATE: dict = {"mesh": None, "rules": dict(DEFAULT_RULES), "off": 0}
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking disabled.
-
-    Newer jax exposes jax.shard_map(check_vma=...); older releases only have
-    jax.experimental.shard_map.shard_map(check_rep=...).
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-        except TypeError:  # intermediate releases expose jax.shard_map with check_rep
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    """jax.shard_map with replication checking disabled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 def in_manual_region() -> bool:
     """True when tracing inside a Manual (shard_map) mesh region, where XLA
-    cannot nest another shard_map. Best-effort across jax versions."""
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        return ctx is not None and not ctx.empty and any(
-            t == jax.sharding.AxisType.Manual for t in ctx.axis_types
-        )
-    except Exception:  # pragma: no cover - older jax lacks the probes
-        return False
+    cannot nest another shard_map."""
+    ctx = jax.sharding.get_abstract_mesh()
+    return not ctx.empty and jax.sharding.AxisType.Manual in ctx.axis_types
 
 
 @contextmanager

@@ -112,6 +112,17 @@ def test_sweep_is_seed_deterministic():
     assert np.array_equal(_bits(a), _bits(b))
 
 
+def test_near_f32_max_field_decodes_finite_within_bound():
+    """The property below once found this: predictions of a field near the
+    f32 limit overflow to inf - inf = NaN, which a ``> bound`` outlier test
+    and a ``> limit`` verify check both let through."""
+    x = np.full((2, 10), 2.9445248e38, np.float32)
+    x[0, 0] = 0.0
+    spec = CompressorSpec(eb=0.1, eb_mode="rel", autotune=False, verify="full")
+    comp = Compressor(spec)
+    _assert_bound(x, comp.decompress(comp.compress(x)), spec)
+
+
 # --------------------------------------------------------------- tier 2
 @pytest.mark.tier2
 def test_hypothesis_bound_or_typed_error():

@@ -156,18 +156,18 @@ def test_tuner_stream_matches_engine_stream():
     import jax.numpy as jnp
 
     from repro.core.autotune import _level_codes_pass
-    from repro.core.predictor import _anchor_mask, compress_blocks
+    from repro.core.predictor import _anchor_mask, compress_blocks, quant_steps
 
     x = FIELDS["smooth"]
     blocks = blk.gather_blocks_batch(blk.pad_field_batch(x[None], blk.ANCHOR_STRIDE), blk.ANCHOR_STRIDE)
-    twoeb = jnp.float32(2 * EB * float(x.max() - x.min()))
+    twoeb, inv2eb = quant_steps(EB * float(x.max() - x.min()))
     levels, splines, schemes = (8, 4, 2, 1), ("cubic",) * 4, ("md",) * 4
     codes_ref = np.asarray(compress_blocks(
-        jnp.asarray(blocks), twoeb, build_steps(3, blk.BLOCK, levels, splines, schemes), 16)[0])
+        jnp.asarray(blocks), twoeb, inv2eb, build_steps(3, blk.BLOCK, levels, splines, schemes), 16)[0])
     recon = jnp.where(jnp.asarray(_anchor_mask(blocks.shape[1:], 16)), jnp.asarray(blocks), 0.0)
     merged = np.full(blocks.shape, -1, np.int32)
     for s, sp, sc in zip(levels, splines, schemes):
-        recon, codes = _level_codes_pass(recon, jnp.asarray(blocks), twoeb,
+        recon, codes = _level_codes_pass(recon, jnp.asarray(blocks), twoeb, inv2eb,
                                          build_steps(3, blk.BLOCK, (s,), (sp,), (sc,)))
         g = np.asarray(codes)
         merged = np.where(g >= 0, g, merged)

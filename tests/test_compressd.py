@@ -111,6 +111,7 @@ def test_roundtrip_and_plan_cache_hit(server):
         st = c.stats()
     assert first["plan_cache"] == "miss" and second["plan_cache"] == "hit"
     assert second["pipeline"] == first["pipeline"]
+    assert first["repairs"] == second["repairs"] == 0  # verify found the bound held
     assert y.shape == x.shape and y.dtype == np.float32
     assert np.max(np.abs(x - y)) <= 1e-3 * (x.max() - x.min()) * (1 + 1e-5)
     rec = st["streams"]["t-roundtrip"]
@@ -197,7 +198,11 @@ def test_backpressure_queue_then_shed():
         t1.join(timeout=30)
         t2.join(timeout=30)
         assert results["a"]["ok"] and results["b"]["ok"]  # queued b completed
-        st = srv.stats()
+        for _ in range(100):  # released just after each reply is sent
+            st = srv.stats()
+            if st["queue"]["inflight_bytes"] == 0:
+                break
+            time.sleep(0.05)
         assert st["queue"]["rejected_overload"] == 1
         assert st["queue"]["inflight_bytes"] == 0  # budget fully released
 
@@ -417,12 +422,18 @@ def test_drain_finishes_inflight_sheds_new():
     def run_slow():
         done["resp"] = slow.request({"op": "sleep", "seconds": 1.0}, b"z" * 64)
 
+    def wait_for(cond):  # poll health, not the clock: a loaded host is slow
+        deadline = time.monotonic() + 10
+        while not cond(probe.health()):
+            assert time.monotonic() < deadline, probe.health()
+            time.sleep(0.01)
+
     t = threading.Thread(target=run_slow)
     t.start()
-    time.sleep(0.3)  # the slow request is in flight
+    wait_for(lambda h: h["inflight_bytes"] > 0)  # the slow request is in flight
     drainer = threading.Thread(target=srv.drain)
     drainer.start()
-    time.sleep(0.3)
+    wait_for(lambda h: h["draining"])
     # new work on a live connection is shed while draining...
     with pytest.raises(ServiceOverloadedError):
         probe.request({"op": "sleep", "seconds": 0.1}, b"w" * 16)
@@ -473,7 +484,11 @@ def test_sigterm_drains_under_load():
 
         t = threading.Thread(target=slow_request)
         t.start()
-        time.sleep(0.4)  # the sleep is in flight on a worker
+        with CompressdClient(addr) as probe:  # poll health, not the clock: a loaded host is slow
+            deadline = time.monotonic() + 10
+            while probe.health()["inflight_bytes"] == 0:  # until the sleep is in flight on a worker
+                assert time.monotonic() < deadline, probe.health()
+                time.sleep(0.01)
         proc.send_signal(_signal.SIGTERM)
         t.join(timeout=30)
         assert inflight["rh"]["ok"]  # in-flight work finished during the drain

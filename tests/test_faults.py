@@ -1,6 +1,6 @@
 """Chaos suite: the fault injectors of :mod:`repro.testing.faults` driven
 against the salvage decoder, the degraded consumers, the retry layer, and
-the engine fallback ladder.
+device faults that must surface as errors.
 
 Deterministic by construction — every random choice flows from
 ``fault_seed()`` (env ``REPRO_FAULTS``, default 20260808), so a CI chaos
@@ -339,13 +339,14 @@ def test_encode_tensor_to_retries_transient_oserror(monkeypatch):
     assert np.abs(out - x).max() <= 1e-3 * rng * (1 + 1e-5)
 
 
-# ----------------------------------------------------- engine fallback ladder
+# ------------------------------------------------ device faults surface
 
 
 def test_device_encode_failure_falls_back_bit_identical(field, monkeypatch):
+    """A device-engine fault raises to the caller; no silent host retry."""
     comp = Compressor(CompressorSpec(eb=1e-2, pipeline="cr", autotune=False, engine="device"))
     ref = comp.compress(field)
-    assert comp.last_telemetry is None or not comp.last_telemetry["fallbacks"]
+    assert comp.last_telemetry["engine"] == "device"
 
     from repro.core.lossless import pipelines as pp
 
@@ -358,12 +359,11 @@ def test_device_encode_failure_falls_back_bit_identical(field, monkeypatch):
 
     monkeypatch.setattr(pp, "encode", sabotaged)
     comp2 = Compressor(CompressorSpec(eb=1e-2, pipeline="cr", autotune=False, engine="device"))
-    out = comp2.compress(field)
-    assert out == ref  # transparent: bit-identical container
-    points = [f["point"] for f in comp2.last_telemetry["fallbacks"]]
-    assert "encode" in points
-    fb = next(f for f in comp2.last_telemetry["fallbacks"] if f["point"] == "encode")
-    assert fb["from"] == "device" and fb["to"] == "numpy" and "injected" in fb["error"]
+    with pytest.raises(RuntimeError, match="injected device-engine failure"):
+        comp2.compress(field)
+    # the host engine never touches the sabotaged device path
+    host = Compressor(CompressorSpec(eb=1e-2, pipeline="cr", autotune=False, engine="numpy"))
+    assert host.compress(field) == ref  # engine bit-identity contract
 
 
 def test_telemetry_resets_between_calls(field):

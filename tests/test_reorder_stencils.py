@@ -88,12 +88,12 @@ def test_exact_on_cubic_polynomial():
     """Cubic splines reproduce cubic polynomials away from block borders."""
     import jax.numpy as jnp
 
-    from repro.core.predictor import compress_blocks
+    from repro.core.predictor import compress_blocks, quant_steps
 
     t = np.linspace(-1, 1, 17).astype(np.float32)
     X, Y, Z = np.meshgrid(t, t, t, indexing="ij")
     poly = (X**3 + Y**3 - Z**3 + X * Y * Z).astype(np.float32)[None]
     steps = build_steps(3, 17, (8, 4, 2, 1), ("cubic",) * 4, ("md",) * 4)
-    codes, outl, recon = compress_blocks(jnp.asarray(poly), jnp.float32(1e-3), steps, 16)
+    codes, outl, recon = compress_blocks(jnp.asarray(poly), *quant_steps(5e-4), steps, 16)
     # reconstruction within eb everywhere (quantization guarantees it)
     assert float(jnp.max(jnp.abs(recon - poly))) <= 1e-3 + 1e-6
