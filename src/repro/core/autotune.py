@@ -44,6 +44,7 @@ from .lossless import pipelines as _pipelines
 from .predictor import CENTER, RADIUS, _anchor_mask, fence, fence_zero, predict, quant_steps, quantize_pred
 from .reorder import reorder_codes_batch
 from .serial import pack_obj, unpack_obj
+from .spans import to_device, to_host
 from .stencils import SCHEMES, SPLINES, build_steps
 
 SAMPLE_FRACTION = 0.002
@@ -326,8 +327,8 @@ def autotune(blocks: np.ndarray, twoeb: float, levels=(8, 4, 2, 1), anchor_every
     ndim = blocks.ndim - 1
     B = blocks.shape[1]
     nb = blocks.shape[0]
-    sample = jnp.asarray(blocks if presampled else blocks[legacy_sample_indices(nb)])
-    am = jnp.asarray(_anchor_mask(sample.shape[1:], anchor_every))
+    sample = to_device(blocks if presampled else blocks[legacy_sample_indices(nb)])
+    am = to_device(_anchor_mask(sample.shape[1:], anchor_every))
     recon = jnp.where(am, sample, 0.0)
     qs = quant_steps(0.5 * twoeb)
     chosen_splines, chosen_schemes = [], []
@@ -370,7 +371,7 @@ def _greedy_levels(sample, qs, stride: int, ndim: int, B: int):
 
     Returns (splines, schemes, per-level code grids big-stride-first).
     """
-    am = jnp.asarray(_anchor_mask(sample.shape[1:], stride))
+    am = to_device(_anchor_mask(sample.shape[1:], stride))
     recon = jnp.where(am, sample, 0.0)
     grids: list[np.ndarray] = []
     splines_sel: list[str] = []
@@ -381,7 +382,7 @@ def _greedy_levels(sample, qs, stride: int, ndim: int, B: int):
             for scheme in candidate_schemes(ndim):
                 steps = build_steps(ndim, B, (s,), (spline,), (scheme,))
                 r2, codes = _level_codes_pass(recon, sample, *qs, steps)
-                codes = np.asarray(codes)
+                codes = to_host(codes)
                 emits = _level_emits(codes)
                 hist = np.bincount(emits, minlength=256)
                 bits = _code_bits(hist, int(hist[0]))
@@ -398,13 +399,13 @@ def _eval_config(sample, qs, stride: int, splines, schemes, ndim: int, B: int):
     """Full-hierarchy evaluation of a (splines, schemes) config with
     feedback; returns per-level code grids. Runs level by level so every
     jitted pass is shared with the greedy sweep's cache."""
-    am = jnp.asarray(_anchor_mask(sample.shape[1:], stride))
+    am = to_device(_anchor_mask(sample.shape[1:], stride))
     recon = jnp.where(am, sample, 0.0)
     grids: list[np.ndarray] = []
     for s, spline, scheme in zip(levels_for_stride(stride), splines, schemes):
         steps = build_steps(ndim, B, (s,), (spline,), (scheme,))
         recon, codes = _level_codes_pass(recon, sample, *qs, steps)
-        grids.append(np.asarray(codes))
+        grids.append(to_host(codes))
     return grids
 
 
@@ -452,7 +453,7 @@ def autotune_plan(
     else:
         nb, sample_np = blocks.shape[0], _sample_blocks(blocks)
     ns = sample_np.shape[0]
-    sample = jnp.asarray(sample_np)
+    sample = to_device(sample_np)
     qs = quant_steps(0.5 * twoeb)  # (twoeb, inv2eb) as the encoder quantizes
     scale = nb / ns  # sampled code bits -> full-field code bits
     n_points = nb * B**ndim  # normalization only; comparisons use totals

@@ -113,14 +113,14 @@ def _scatter_index(shape_padded: tuple[int, ...], stride: int = ANCHOR_STRIDE):
     re-upload, and the cache holds 4 bytes/cell for a handful of shapes
     rather than unbounded int64 host copies.
     """
-    import jax.numpy as jnp
+    from .spans import to_device
 
     nbs = block_grid(shape_padded, stride)
     nb = int(np.prod(nbs))
     B = stride + 1
     src = np.arange(nb * B ** len(shape_padded), dtype=np.int32)
     idx = scatter_blocks(src.reshape((nb,) + (B,) * len(shape_padded)), shape_padded, stride)
-    return jnp.asarray(idx.reshape(-1))  # uncommitted: follows the operand's device
+    return to_device(idx.reshape(-1))  # uncommitted: follows the operand's device
 
 
 def scatter_blocks_batch_jnp(blocks, idx, batch: int, shape_padded: tuple[int, ...]):
@@ -173,7 +173,7 @@ def _anchor_index(shape_padded: tuple[int, ...], stride: int = ANCHOR_STRIDE):
     (0 where masked off). Gather+where instead of a strided scatter — the
     fast direction on XLA:CPU (same trade as _scatter_index).
     """
-    import jax.numpy as jnp
+    from .spans import to_device
 
     coords = np.meshgrid(*(np.arange(d) for d in shape_padded), indexing="ij")
     mask = np.ones(shape_padded, bool)
@@ -182,7 +182,7 @@ def _anchor_index(shape_padded: tuple[int, ...], stride: int = ANCHOR_STRIDE):
     ashape = tuple((d - 1) // stride + 1 for d in shape_padded)
     idx = np.ravel_multi_index(tuple(c // stride for c in coords), ashape).astype(np.int32)
     idx[~mask] = 0
-    return jnp.asarray(idx.reshape(-1)), jnp.asarray(mask.reshape(-1))
+    return to_device(idx.reshape(-1)), to_device(mask.reshape(-1))
 
 
 def place_anchors_batch_jnp(shape_padded: tuple[int, ...], anchors, ix):

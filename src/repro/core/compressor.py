@@ -73,7 +73,6 @@ import functools
 import json
 import struct
 import threading
-import time
 import zlib
 
 import jax
@@ -83,6 +82,7 @@ import numpy as np
 from . import blocks as blk
 from . import frames as frames_mod
 from . import lorenzo as lor
+from . import spans
 from .errors import BoundViolationError, ContainerError, DamageReport, FrameCRCError, SpecError
 from .retry import RetryPolicy
 from .autotune import (
@@ -449,7 +449,8 @@ class Compressor:
         #     the requested backend/engine, the plan-cache outcome
         #     ("plan_cache": "hit"/"miss") and the chosen pipeline.
         #     decompress() additionally records a "decode" dict (engine,
-        #     out, seconds, bytes, mbps).
+        #     out, seconds, bytes, mbps). Both put the call's spans and
+        #     counters (repro.core.spans.Record) under "trace".
         #   last_damage — reset by decompress(); under on_error="skip"/
         #     "fill" records the DamageReport and the per-chunk intact
         #     mask of a salvaged v3 container (None = fully intact).
@@ -534,11 +535,17 @@ class Compressor:
         if not self._telemetry_hold:
             self.last_telemetry = None
         self._telemetry()
-        x = np.ascontiguousarray(x, np.float32)
-        fin = np.isfinite(x)
-        if not fin.all():
-            return self._compress_nonfinite(x, fin)
-        return self._compress_finite(x)
+        with spans.call("compress") as rec:
+            with spans.span("compress.ingest"):
+                x = np.ascontiguousarray(spans.to_host(x), np.float32)
+                if isinstance(rec, spans.Record):
+                    spans.count("in_bytes", int(x.nbytes))
+                fin = np.isfinite(x)
+                finite = bool(fin.all())
+            buf = self._compress_finite(x) if finite else self._compress_nonfinite(x, fin)
+        if isinstance(rec, spans.Record):
+            self._telemetry()["trace"] = rec
+        return buf
 
     def _compress_finite(self, x: np.ndarray) -> bytes:
         """The historical compress body: ``x`` is canonical f32, all-finite."""
@@ -551,7 +558,8 @@ class Compressor:
             eb_abs = self._psnr_target_eb(x)
             psnr_hdr["psnr_target"] = float(sp.psnr_target)
         else:
-            eb_abs = self._abs_eb(x)
+            with spans.span("compress.ingest"):
+                eb_abs = self._abs_eb(x)
         base_hdr = {
             "shape": list(x.shape),
             "predictor": sp.predictor,
@@ -611,7 +619,7 @@ class Compressor:
 
     def _decompress_nfsafe(self, header, sections, shape, device: bool = False) -> np.ndarray:
         ihdr, isec = _sections_unpack(sections[0])
-        y = np.asarray(self._decompress_sections(ihdr, isec, device=device))
+        y = spans.to_host(self._decompress_sections(ihdr, isec, device=device))
         flat = y.reshape(-1).astype(np.float32).copy()
         mask = np.unpackbits(np.frombuffer(sections[1], np.uint8), count=flat.size).astype(bool)
         pats = np.frombuffer(zlib.decompress(sections[2]), np.uint32)
@@ -674,7 +682,8 @@ class Compressor:
         if sp.verify == "off":
             return buf
         tel = self._telemetry()
-        max_err, checked = self._verify_check(x, buf, bound=bound, rel=rel)
+        with spans.span("compress.verify"):
+            max_err, checked = self._verify_check(x, buf, bound=bound, rel=rel)
         repairs = 0
         cur = float(bound)
         limit = bound * (1.0 + _VERIFY_SLACK) + 1e-12  # f32 rounding headroom
@@ -698,7 +707,8 @@ class Compressor:
                     f"bound violation (max err {max_err:.6g} > {bound:.6g}) and repair "
                     f"rung {repairs} cannot encode at eb={cur:.6g}: {e}",
                     max_err=max_err, bound=bound, repairs=repairs) from e
-            max_err, checked = self._verify_check(x, buf, bound=bound, rel=rel)
+            with spans.span("compress.verify"):
+                max_err, checked = self._verify_check(x, buf, bound=bound, rel=rel)
         tel["verify"] = {"mode": sp.verify, "checked": checked, "max_err": max_err,
                          "bound": bound, "repairs": repairs}
         return buf
@@ -725,9 +735,9 @@ class Compressor:
         sp = self.spec
         is_dev = pipelines._is_jax(seq)
         if sp.engine == "device" and not is_dev:
-            seq = jnp.asarray(np.ascontiguousarray(seq, np.uint8))
+            seq = spans.to_device(np.ascontiguousarray(seq, np.uint8))
         elif sp.engine == "numpy" and is_dev:
-            seq = np.asarray(seq)
+            seq = spans.to_host(seq)
         fixed = sp.pipeline if sp.pipeline != "auto" else pipeline_override
         if fixed is not None:
             hdr = {"pipeline": fixed}
@@ -822,7 +832,7 @@ class Compressor:
 
             codes_b, outl_b, _ = compress_blocks_pallas(blocks, 2.0 * eb_abs, steps, stride)
             return self._maybe_fault_codes(codes_b), outl_b
-        codes_b, outl_b, _ = compress_blocks(jnp.asarray(blocks), *quant_steps(eb_abs), steps, stride)
+        codes_b, outl_b, _ = compress_blocks(spans.to_device(blocks), *quant_steps(eb_abs), steps, stride)
         return self._maybe_fault_codes(codes_b), outl_b
 
     @staticmethod
@@ -833,7 +843,7 @@ class Compressor:
         code==0 <=> outlier invariant; it never fires in production."""
         if _CODE_FAULT is None:
             return codes_b
-        return _CODE_FAULT(np.asarray(codes_b))
+        return _CODE_FAULT(spans.to_host(codes_b))
 
     def _tune_interp(self, blocks: np.ndarray, eb_abs: float, batch: int, padded_shapes,
                      presampled_of: int | None = None):
@@ -873,34 +883,37 @@ class Compressor:
         flows into the encoding engine without ever visiting host.
         """
         sp = self.spec
-        if pipelines._is_jax(cgrid):
-            from .reorder import reorder_codes_batch_device
+        with spans.span("compress.reorder"):
+            if pipelines._is_jax(cgrid):
+                from .reorder import reorder_codes_batch_device
 
-            seq = reorder_codes_batch_device(cgrid, stride, sp.reorder)
-        else:
-            seq = reorder_codes_batch(cgrid, stride, sp.reorder)
-        payload, penc = self._encode_codes(seq, pipeline_override=pipeline_override)
-        header = dict(
-            base_hdr,
-            mode="interp",
-            anchor_stride=int(stride),  # may differ from the spec under a plan
-            padded=list(cgrid.shape[1:]),
-            batch=int(cgrid.shape[0]),
-            splines=list(splines),
-            schemes=list(schemes),
-            reorder=bool(sp.reorder),
-            n_outliers=int(oi.size),
-            arith=ARITH,
-            **penc,
-        )
-        # No separate plan blob: the plan IS (anchor_stride, splines, schemes),
-        # already serialized above — zero container overhead vs a fixed spec.
-        # Compressor.inspect reassembles the "pplan" view from those fields;
-        # the full diagnostics (scores, candidates) stay on self.last_plan.
-        anc = anc.astype(np.float32, copy=False)
-        return _sections_pack(header, [payload, anc.tobytes(),
-                                       oi.astype(np.int64, copy=False).tobytes(),
-                                       ov.astype(np.float32, copy=False).tobytes()])
+                seq = reorder_codes_batch_device(cgrid, stride, sp.reorder)
+            else:
+                seq = reorder_codes_batch(cgrid, stride, sp.reorder)
+        with spans.span("compress.encode"):
+            payload, penc = self._encode_codes(seq, pipeline_override=pipeline_override)
+        with spans.span("compress.pack"):
+            header = dict(
+                base_hdr,
+                mode="interp",
+                anchor_stride=int(stride),  # may differ from the spec under a plan
+                padded=list(cgrid.shape[1:]),
+                batch=int(cgrid.shape[0]),
+                splines=list(splines),
+                schemes=list(schemes),
+                reorder=bool(sp.reorder),
+                n_outliers=int(oi.size),
+                arith=ARITH,
+                **penc,
+            )
+            # No separate plan blob: the plan IS (anchor_stride, splines, schemes),
+            # already serialized above — zero container overhead vs a fixed spec.
+            # Compressor.inspect reassembles the "pplan" view from those fields;
+            # the full diagnostics (scores, candidates) stay on self.last_plan.
+            anc = anc.astype(np.float32, copy=False)
+            return _sections_pack(header, [payload, anc.tobytes(),
+                                           oi.astype(np.int64, copy=False).tobytes(),
+                                           ov.astype(np.float32, copy=False).tobytes()])
 
     def _plan_cache_key(self, x: np.ndarray):
         """Plan-cache signature of this field under this spec, or ``None``
@@ -922,17 +935,18 @@ class Compressor:
 
     def _compress_interp(self, x: np.ndarray, eb_abs: float, base_hdr: dict) -> bytes:
         sp = self.spec
-        xb, spatial = self._spatial_view(x)
-        ndim = len(spatial)
-        batch = xb.shape[0]
-        padded = blk.pad_field_batch(xb, blk.ANCHOR_STRIDE)
-        padded_shapes = padded.shape[1:]
-        blocks = blk.gather_blocks_batch(padded, blk.ANCHOR_STRIDE)
-        # plan cache: a recurring field signature replays the recorded
-        # tuning outcome — predictor plan AND (pipeline="auto") the
-        # orchestrator's pipeline choice — skipping both tuners entirely
-        ckey = self._plan_cache_key(x)
-        cached = self.plan_cache.get(ckey) if ckey is not None else None
+        with spans.span("compress.prep"):
+            xb, spatial = self._spatial_view(x)
+            ndim = len(spatial)
+            batch = xb.shape[0]
+            padded = blk.pad_field_batch(xb, blk.ANCHOR_STRIDE)
+            padded_shapes = padded.shape[1:]
+            blocks = blk.gather_blocks_batch(padded, blk.ANCHOR_STRIDE)
+            # plan cache: a recurring field signature replays the recorded
+            # tuning outcome — predictor plan AND (pipeline="auto") the
+            # orchestrator's pipeline choice — skipping both tuners entirely
+            ckey = self._plan_cache_key(x)
+            cached = self.plan_cache.get(ckey) if ckey is not None else None
         pipe_override = None
         if cached is not None:
             self._telemetry()["plan_cache"] = "hit"
@@ -944,24 +958,27 @@ class Compressor:
         else:
             if ckey is not None:
                 self._telemetry()["plan_cache"] = "miss"
-            stride, splines, schemes = self._tune_interp(blocks, eb_abs, batch, padded_shapes)
-        steps = build_steps(ndim, blk.BLOCK, levels_for_stride(stride), splines, schemes)
-        codes_b, outl_b = self._run_predictor(blocks, eb_abs, steps, stride, ndim)
-        anc = blk.anchor_grid_batch(padded, stride)
-        if sp.engine == "device":
-            # fused tail: codes stay device-resident through block scatter,
-            # level reorder, and the encoding engine (inside _pack_interp);
-            # outliers come from the code==0 <=> outlier invariant the
-            # sharded path already relies on — no outlier grid crosses over
-            cgrid = blk.scatter_blocks_batch_jnp(jnp.asarray(codes_b), blk._scatter_index(padded_shapes),
-                                                 batch, padded_shapes)
-            oi = np.asarray(jnp.flatnonzero(cgrid.reshape(-1) == 0)).astype(np.int64)
-        else:
-            codes_b, outl_b = np.asarray(codes_b), np.asarray(outl_b)
-            cgrid = blk.scatter_blocks_batch(codes_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
-            ogrid = blk.scatter_blocks_batch(outl_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
-            oi = np.flatnonzero(ogrid.reshape(-1)).astype(np.int64)  # already batch-global
-        ov = padded.reshape(-1)[oi]
+            with spans.span("compress.tune"):
+                stride, splines, schemes = self._tune_interp(blocks, eb_abs, batch, padded_shapes)
+        with spans.span("compress.predict"):
+            steps = build_steps(ndim, blk.BLOCK, levels_for_stride(stride), splines, schemes)
+            codes_b, outl_b = self._run_predictor(blocks, eb_abs, steps, stride, ndim)
+        with spans.span("compress.scatter"):
+            anc = blk.anchor_grid_batch(padded, stride)
+            if sp.engine == "device":
+                # fused tail: codes stay device-resident through block scatter,
+                # level reorder, and the encoding engine (inside _pack_interp);
+                # outliers come from the code==0 <=> outlier invariant the
+                # sharded path already relies on — no outlier grid crosses over
+                cgrid = blk.scatter_blocks_batch_jnp(spans.to_device(codes_b), blk._scatter_index(padded_shapes),
+                                                     batch, padded_shapes)
+                oi = spans.to_host(jnp.flatnonzero(cgrid.reshape(-1) == 0)).astype(np.int64)
+            else:
+                codes_b, outl_b = spans.to_host(codes_b), spans.to_host(outl_b)
+                cgrid = blk.scatter_blocks_batch(codes_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
+                ogrid = blk.scatter_blocks_batch(outl_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
+                oi = np.flatnonzero(ogrid.reshape(-1)).astype(np.int64)  # already batch-global
+            ov = padded.reshape(-1)[oi]
         buf = self._pack_interp(base_hdr, cgrid=cgrid, anc=anc, oi=oi, ov=ov,
                                 stride=stride, splines=splines, schemes=schemes,
                                 pipeline_override=pipe_override)
@@ -979,16 +996,17 @@ class Compressor:
     def _compress_lorenzo(self, x: np.ndarray, eb_abs: float, base_hdr: dict) -> bytes:
         xb, spatial = self._spatial_view(x)
         twoeb = jnp.float32(2.0 * eb_abs)
-        codes, outl, cfull, _ = lor.lorenzo_encode(jnp.asarray(xb), twoeb, len(spatial))
-        codes, outl, cfull = np.asarray(codes), np.asarray(outl), np.asarray(cfull)
+        codes, outl, cfull, _ = lor.lorenzo_encode(spans.to_device(xb), twoeb, len(spatial))
+        codes, outl, cfull = spans.to_host(codes), spans.to_host(outl), spans.to_host(cfull)
         fi = np.flatnonzero(outl.reshape(-1))
-        payload, penc = self._encode_codes(codes.reshape(-1))
+        with spans.span("compress.encode"):
+            payload, penc = self._encode_codes(codes.reshape(-1))
         header = dict(base_hdr, mode="lorenzo", batch=int(xb.shape[0]), spatial=list(spatial), n_outliers=int(fi.size), **penc)
         return _sections_pack(header, [payload, fi.astype(np.int64).tobytes(), cfull.reshape(-1)[fi].astype(np.int32).tobytes()])
 
     def _compress_offset1d(self, x: np.ndarray, eb_abs: float, base_hdr: dict) -> bytes:
         twoeb = jnp.float32(2.0 * eb_abs)
-        codes = np.asarray(lor.offset1d_encode(jnp.asarray(x), twoeb))
+        codes = spans.to_host(lor.offset1d_encode(spans.to_device(x), twoeb))
         payload, hdr = fl_encode(codes)
         header = dict(base_hdr, mode="offset1d", fl=hdr)
         return _sections_pack(header, [payload])
@@ -1046,7 +1064,7 @@ class Compressor:
 
     def _decompress_pw_rel(self, header, sections, shape, device: bool = False) -> np.ndarray:
         ihdr, isec = _sections_unpack(sections[0])
-        y = np.asarray(self._decompress_sections(ihdr, isec, device=device))
+        y = spans.to_host(self._decompress_sections(ihdr, isec, device=device))
         sign = np.unpackbits(np.frombuffer(sections[1], np.uint8), count=y.size).astype(bool)
         zero = np.unpackbits(np.frombuffer(sections[2], np.uint8), count=y.size).astype(bool)
         out = np.exp(y.reshape(-1).astype(np.float64))
@@ -1159,7 +1177,8 @@ class Compressor:
         bounces through host memory. Bytes-for-bytes the result matches
         the numpy path (the engine bit-identity contract); a device decode
         failure raises. Each call also records
-        ``last_telemetry["decode"]`` (engine, out, seconds, bytes, MB/s).
+        ``last_telemetry["decode"]`` (engine, out, seconds, bytes, MB/s),
+        its seconds read from the call's span record (``["trace"]``).
 
         ``on_error`` — degraded-mode decode of damaged containers:
 
@@ -1186,45 +1205,51 @@ class Compressor:
             self.last_telemetry = None
         tel = self._telemetry()
         want_dev = self.spec.engine == "device" or (self.spec.engine == "auto" and out == "device")
-        t0 = time.perf_counter()
         self.last_damage = None
-        if frames_mod.is_v3(buf):
-            result = self._decompress_v3(buf, frames, on_error=on_error,
-                                         fill_value=fill_value, out=out)
-        else:
-            if frames is not None:
-                raise ValueError("frames= is only meaningful for v3 (chunked) containers")
-            try:
-                header, sections = _sections_unpack(buf)
-                result = self._decompress_sections(header, sections, device=want_dev)
-            except Exception as e:
-                if on_error != "fill":
-                    raise
-                # salvage a single container only when its header still tells
-                # us the field geometry; otherwise there is nothing to fill
+        with spans.call("decompress") as rec:
+            if isinstance(rec, spans.Record):
+                spans.count("in_bytes", len(buf))
+            if frames_mod.is_v3(buf):
+                result = self._decompress_v3(buf, frames, on_error=on_error,
+                                             fill_value=fill_value, out=out)
+            else:
+                if frames is not None:
+                    raise ValueError("frames= is only meaningful for v3 (chunked) containers")
                 try:
-                    header, _ = _sections_unpack(buf)
-                    shape = tuple(header["shape"])
-                except Exception:
-                    raise e from None
-                report = DamageReport()
-                report.add("decode", 0, index=0, detail=repr(e))
-                report.frames_damaged = 1
-                self.last_damage = {"report": report, "chunks_ok": [False], "on_error": on_error}
-                result = np.full(shape, np.float32(fill_value), np.float32)
-        if out == "device" and isinstance(result, np.ndarray):
-            result = jnp.asarray(result)
-        elif out == "numpy" and not isinstance(result, np.ndarray):
-            result = np.asarray(result)
+                    with spans.span("decompress.unpack"):
+                        header, sections = _sections_unpack(buf)
+                    result = self._decompress_sections(header, sections, device=want_dev)
+                except Exception as e:
+                    if on_error != "fill":
+                        raise
+                    # salvage a single container only when its header still tells
+                    # us the field geometry; otherwise there is nothing to fill
+                    try:
+                        header, _ = _sections_unpack(buf)
+                        shape = tuple(header["shape"])
+                    except Exception:
+                        raise e from None
+                    report = DamageReport()
+                    report.add("decode", 0, index=0, detail=repr(e))
+                    report.frames_damaged = 1
+                    self.last_damage = {"report": report, "chunks_ok": [False], "on_error": on_error}
+                    result = np.full(shape, np.float32(fill_value), np.float32)
+            with spans.span("decompress.reconstruct"):
+                if out == "device" and isinstance(result, np.ndarray):
+                    result = spans.to_device(result)
+                elif out == "numpy" and not isinstance(result, np.ndarray):
+                    result = spans.to_host(result)
+                if not hold and not isinstance(result, np.ndarray):
+                    result.block_until_ready()  # honest timing for device results
         if not hold:
-            if not isinstance(result, np.ndarray):
-                result.block_until_ready()  # honest timing for device results
-            dt = time.perf_counter() - t0
+            dt = rec.seconds
             tel["decode"] = {
                 "engine": "device" if want_dev else "numpy", "out": out,
                 "seconds": dt, "bytes": int(result.nbytes),
                 "mbps": (result.nbytes / dt / 1e6) if dt > 0 else 0.0,
             }
+        if isinstance(rec, spans.Record):
+            tel["trace"] = rec
         return result
 
     def _decompress_sections(self, header, sections, device: bool = False) -> np.ndarray:
@@ -1239,8 +1264,8 @@ class Compressor:
             return self._decompress_lorenzo(header, sections, shape, device=device)
         if mode == "offset1d":
             codes = fl_decode(sections[0], header["fl"])
-            out = lor.offset1d_decode(jnp.asarray(codes), jnp.float32(2.0 * header["eb_abs"]))
-            return out.reshape(shape) if device else np.asarray(out).reshape(shape)
+            out = lor.offset1d_decode(spans.to_device(codes), jnp.float32(2.0 * header["eb_abs"]))
+            return out.reshape(shape) if device else spans.to_host(out).reshape(shape)
         if mode == "pw_rel":
             return self._decompress_pw_rel(header, sections, shape, device=device)
         if mode == "nfsafe":
@@ -1250,65 +1275,73 @@ class Compressor:
         raise ValueError(mode)
 
     def _decompress_interp(self, header, sections, shape, device: bool = False) -> np.ndarray:
-        stride = header["anchor_stride"]
-        padded_shapes = tuple(header["padded"])
-        batch = header["batch"]
-        ndim = len(padded_shapes)
-        eb_abs = header["eb_abs"]
-        psize = int(np.prod(padded_shapes))
-        anc_shape = tuple((d - 1) // stride + 1 for d in padded_shapes)
-        levels = levels_for_stride(stride)
-        # Containers that predate recorded step tables (or hand-rolled v1
-        # headers without them) decode with the default cubic/md hierarchy.
-        splines = tuple(header.get("splines", ("cubic",) * len(levels)))
-        schemes = tuple(header.get("schemes", ("md",) * len(levels)))
-        steps = build_steps(ndim, blk.BLOCK, levels, splines, schemes)
-        spatial = shape[len(shape) - ndim :] if len(shape) >= ndim else shape
-        sl = (slice(None),) + tuple(slice(0, s) for s in spatial)
-        anc = np.frombuffer(sections[1], np.float32)
-        oi = np.frombuffer(sections[2], np.int64)
-        ov = np.frombuffer(sections[3], np.float32)
-        arith = header.get("arith")
-        if arith not in (None, ARITH):
-            raise ValueError(f"unknown predictor arithmetic {arith!r}; this build replays {ARITH}")
-        # arith containers were quantized with quant_steps' step; older ones with 2 * eb_abs
-        twoeb = np.float32(2.0 * eb_abs) if arith is None else quant_steps(eb_abs)[0]
+        with spans.span("decompress.unpack"):
+            stride = header["anchor_stride"]
+            padded_shapes = tuple(header["padded"])
+            batch = header["batch"]
+            ndim = len(padded_shapes)
+            eb_abs = header["eb_abs"]
+            psize = int(np.prod(padded_shapes))
+            anc_shape = tuple((d - 1) // stride + 1 for d in padded_shapes)
+            levels = levels_for_stride(stride)
+            # Containers that predate recorded step tables (or hand-rolled v1
+            # headers without them) decode with the default cubic/md hierarchy.
+            splines = tuple(header.get("splines", ("cubic",) * len(levels)))
+            schemes = tuple(header.get("schemes", ("md",) * len(levels)))
+            steps = build_steps(ndim, blk.BLOCK, levels, splines, schemes)
+            spatial = shape[len(shape) - ndim :] if len(shape) >= ndim else shape
+            sl = (slice(None),) + tuple(slice(0, s) for s in spatial)
+            anc = np.frombuffer(sections[1], np.float32)
+            oi = np.frombuffer(sections[2], np.int64)
+            ov = np.frombuffer(sections[3], np.float32)
+            arith = header.get("arith")
+            if arith not in (None, ARITH):
+                raise ValueError(f"unknown predictor arithmetic {arith!r}; this build replays {ARITH}")
+            # arith containers were quantized with quant_steps' step; older ones with 2 * eb_abs
+            twoeb = np.float32(2.0 * eb_abs) if arith is None else quant_steps(eb_abs)[0]
         if device and arith is not None:
             # device-resident tail: codes decode through the stage twins and
             # every hop to the reconstructed field is a jnp gather — same
             # bytes as the numpy path below (bit-identity contract).
             # Containers without "arith" take the host path, whose replay
             # must run on XLA:CPU.
-            seq = pipelines.decode(sections[0], device=True)
-            ovflat = jnp.zeros(batch * psize, jnp.float32)
-            if oi.size:  # outlier indices are batch-global and unique
-                ovflat = ovflat.at[jnp.asarray(oi)].set(jnp.asarray(ov))
-            reorder = bool(header.get("reorder", True))
-            out = _reconstruct_device(
-                seq, jnp.asarray(anc).reshape((batch,) + anc_shape), ovflat, twoeb,
-                _restore_gather(padded_shapes, stride, reorder), blk._anchor_index(padded_shapes, stride),
-                blk._scatter_index(padded_shapes), batch=batch, padded=padded_shapes, stride=stride, steps=steps)
+            with spans.span("decompress.lossless"):
+                seq = pipelines.decode(sections[0], device=True)
+            with spans.span("decompress.reconstruct"):
+                ovflat = jnp.zeros(batch * psize, jnp.float32)
+                if oi.size:  # outlier indices are batch-global and unique
+                    ovflat = ovflat.at[spans.to_device(oi)].set(spans.to_device(ov))
+                reorder = bool(header.get("reorder", True))
+                out = _reconstruct_device(
+                    seq, spans.to_device(anc).reshape((batch,) + anc_shape), ovflat, twoeb,
+                    _restore_gather(padded_shapes, stride, reorder), blk._anchor_index(padded_shapes, stride),
+                    blk._scatter_index(padded_shapes), batch=batch, padded=padded_shapes, stride=stride,
+                    steps=steps)
+                return out[sl].reshape(shape)
+        with spans.span("decompress.lossless"):
+            seq = pipelines.decode(sections[0])
+        with spans.span("decompress.restore"):
+            cgrid = restore_codes_batch(seq, batch, padded_shapes, fill=128, dtype=np.uint8,
+                                        stride=stride, reorder=header.get("reorder", True))
+            agrid = blk.place_anchors_batch(padded_shapes, anc.reshape((batch,) + anc_shape), stride)
+            ovflat = np.zeros(batch * psize, np.float32)
+            ovflat[oi] = ov  # outlier indices are batch-global
+            ovgrid = ovflat.reshape((batch,) + padded_shapes)
+            cb = blk.gather_blocks_batch(cgrid, blk.ANCHOR_STRIDE)
+            ab = blk.gather_blocks_batch(agrid, blk.ANCHOR_STRIDE)
+            vb = blk.gather_blocks_batch(ovgrid, blk.ANCHOR_STRIDE)
+        with spans.span("decompress.reconstruct"):
+            if arith is None:
+                # written by the matmul-form encoder on XLA:CPU: replayed there,
+                # so such archives decode to the same floats on any host
+                with jax.default_device(jax.devices("cpu")[0]):
+                    recon_b = np.asarray(decompress_blocks_matmul(cb, ab, vb, twoeb, steps, stride))
+            else:
+                recon_b = spans.to_host(decompress_blocks(spans.to_device(cb), spans.to_device(ab),
+                                                          spans.to_device(vb), twoeb, steps, stride))
+        with spans.span("decompress.scatter"):
+            out = blk.scatter_blocks_batch(recon_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
             return out[sl].reshape(shape)
-        seq = pipelines.decode(sections[0])
-        cgrid = restore_codes_batch(seq, batch, padded_shapes, fill=128, dtype=np.uint8,
-                                    stride=stride, reorder=header.get("reorder", True))
-        agrid = blk.place_anchors_batch(padded_shapes, anc.reshape((batch,) + anc_shape), stride)
-        ovflat = np.zeros(batch * psize, np.float32)
-        ovflat[oi] = ov  # outlier indices are batch-global
-        ovgrid = ovflat.reshape((batch,) + padded_shapes)
-        cb = blk.gather_blocks_batch(cgrid, blk.ANCHOR_STRIDE)
-        ab = blk.gather_blocks_batch(agrid, blk.ANCHOR_STRIDE)
-        vb = blk.gather_blocks_batch(ovgrid, blk.ANCHOR_STRIDE)
-        if arith is None:
-            # written by the matmul-form encoder on XLA:CPU: replayed there,
-            # so such archives decode to the same floats on any host
-            with jax.default_device(jax.devices("cpu")[0]):
-                recon_b = np.asarray(decompress_blocks_matmul(cb, ab, vb, twoeb, steps, stride))
-        else:
-            recon_b = np.asarray(decompress_blocks(jnp.asarray(cb), jnp.asarray(ab), jnp.asarray(vb),
-                                                   twoeb, steps, stride))
-        out = blk.scatter_blocks_batch(recon_b, batch, padded_shapes, blk.ANCHOR_STRIDE)
-        return out[sl].reshape(shape)
 
     @staticmethod
     def _chunk_shape(header: dict, i: int) -> tuple:
@@ -1402,7 +1435,7 @@ class Compressor:
         if len(parts) == 1:
             return parts[0]
         if out == "device":
-            return jnp.concatenate([jnp.asarray(p) for p in parts], axis=axis)
+            return jnp.concatenate([spans.to_device(p) for p in parts], axis=axis)
         return np.concatenate(parts, axis=axis)
 
     def _decompress_lorenzo(self, header, sections, shape, device: bool = False) -> np.ndarray:
@@ -1414,7 +1447,7 @@ class Compressor:
             codes = seq.reshape((batch,) + spatial)
             ofull = jnp.zeros(codes.size, jnp.int32)
             if oi.size:
-                ofull = ofull.at[jnp.asarray(oi)].set(jnp.asarray(ov))
+                ofull = ofull.at[spans.to_device(oi)].set(spans.to_device(ov))
             out = lor.lorenzo_decode(codes, ofull.reshape(codes.shape),
                                      jnp.float32(2.0 * header["eb_abs"]), len(spatial))
             return out.reshape(shape)
@@ -1422,8 +1455,9 @@ class Compressor:
         codes = seq.reshape((batch,) + spatial)
         ofull = np.zeros(codes.size, np.int32)
         ofull[oi] = ov
-        out = lor.lorenzo_decode(jnp.asarray(codes), jnp.asarray(ofull.reshape(codes.shape)), jnp.float32(2.0 * header["eb_abs"]), len(spatial))
-        return np.asarray(out).reshape(shape)
+        out = lor.lorenzo_decode(spans.to_device(codes), spans.to_device(ofull.reshape(codes.shape)),
+                                 jnp.float32(2.0 * header["eb_abs"]), len(spatial))
+        return spans.to_host(out).reshape(shape)
 
 
 # ------------------------------------------------------------------ presets

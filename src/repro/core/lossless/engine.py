@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..spans import to_device, to_host
 from . import huffman as _hf
 from . import rre as _rre
 
@@ -86,7 +87,7 @@ def as_device_u8(x) -> jax.Array:
     """
     if isinstance(x, (bytes, bytearray, memoryview)):
         x = np.frombuffer(x, np.uint8)
-    arr = x if is_device(x) else jnp.asarray(np.ascontiguousarray(x))
+    arr = x if is_device(x) else to_device(np.ascontiguousarray(x))
     if arr.dtype != jnp.uint8:
         arr = arr.astype(jnp.uint8)
     return arr.reshape(-1)
@@ -95,7 +96,7 @@ def as_device_u8(x) -> jax.Array:
 def _host_u8(x) -> np.ndarray:
     """Flat uint8 *host* view of a payload (zero-copy where possible)."""
     if is_device(x):
-        return np.asarray(x, np.uint8).reshape(-1)
+        return to_host(x, np.uint8).reshape(-1)
     if isinstance(x, np.ndarray):
         return np.ascontiguousarray(x).view(np.uint8).reshape(-1)
     return np.frombuffer(x, np.uint8)
@@ -111,7 +112,7 @@ def histogram256_device(data) -> np.ndarray:
 
     Compiled on TPU this is the Pallas histogram256 kernel (one-hot
     contraction per tile); on the CPU backend, device memory IS host
-    memory (``np.asarray`` is zero-copy), so the counts come from a
+    memory (``to_host`` is zero-copy), so the counts come from a
     symbol-PAIR ``np.bincount`` over the u16 view folded back to 256 bins
     — ~6x faster than a byte-wise bincount because it halves the element
     count fed through numpy's index conversion. Counts equal
@@ -129,8 +130,8 @@ def histogram256_device(data) -> np.ndarray:
         hist = histogram256_raw(d, False)
         if pad:
             hist = hist.at[0].add(-pad)
-        return np.asarray(hist, np.int64)
-    dn = np.asarray(d)
+        return to_host(hist, np.int64)
+    dn = to_host(d)
     n2 = dn.size & ~1
     if n2 >= (2 << 20):  # split across the shared pool like huffman.encode
         from .huffman import _executor
@@ -269,14 +270,14 @@ def _slab_bridge(emit_a_out, m: int):
     """Host assist + phase-B dispatch for one slab's phase-A outputs.
 
     Builds the word-boundary table from the flag bits and the per-chunk
-    scalars (see the section comment); the ``np.asarray`` pulls block on
+    scalars (see the section comment); the ``to_host`` pulls block on
     this slab's phase A only, so other slabs' device work keeps running.
     """
     nck = m // _hf.CHUNK
     v2, hi, sh, first, chunk_bytes, byte_off, last_w = emit_a_out
-    firsts = np.flatnonzero(np.asarray(first)).astype(np.int32)
-    bo = np.asarray(byte_off)
-    lws = np.asarray(last_w)
+    firsts = np.flatnonzero(to_host(first)).astype(np.int32)
+    bo = to_host(byte_off)
+    lws = to_host(last_w)
     total = int(bo[-1])
     nwords = (total + 3) >> 2
     # seam skips: chunk payloads are byte- (not word-) aligned, so the gap
@@ -296,7 +297,7 @@ def _slab_bridge(emit_a_out, m: int):
     bounds[bounds_core.size :] = nw
     bad = np.full(max(nck, 1), alloc + 1, np.int32)  # out of range: dropped
     bad[: skip_words.size] = (skip_words + 1).astype(np.int32)
-    bits, cb = _hf_emit_b(v2, hi, sh, jnp.asarray(bounds), jnp.asarray(bad), chunk_bytes)
+    bits, cb = _hf_emit_b(v2, hi, sh, to_device(bounds), to_device(bad), chunk_bytes)
     return bits[:total], cb
 
 
@@ -327,7 +328,7 @@ def hf_encode_device(data):
     n_full = (n // _hf.CHUNK) * _hf.CHUNK
     cb_parts, bit_parts = [], []
     if n_full:
-        tblc = jnp.asarray(_pair_tables(lens, codes))
+        tblc = to_device(_pair_tables(lens, codes))
         slab_syms = min(_PAR_SLAB, _SLAB_CHUNKS * _hf.CHUNK)  # u32 cursors
         slab_syms = max(slab_syms - slab_syms % _hf.CHUNK, _hf.CHUNK)  # chunk-aligned
         cuts = list(range(0, n_full, slab_syms)) + [n_full]
@@ -340,11 +341,11 @@ def hf_encode_device(data):
             cb_parts.append(cb)
             bit_parts.append(bits)
     if n > n_full or n == 0:  # partial/empty tail chunk: reference encoder
-        tail_bits, tail_cb = _hf._encode_slab(np.asarray(d[n_full:]), tbl_np)
-        cb_parts.append(jnp.asarray(np.frombuffer(tail_cb.tobytes(), np.uint8)))
-        bit_parts.append(jnp.asarray(np.frombuffer(tail_bits, np.uint8)))
-    payload = jnp.concatenate([jnp.asarray(lens)] + cb_parts + bit_parts)
-    chunk_bytes = np.concatenate([np.asarray(p) for p in cb_parts]).view("<u2")
+        tail_bits, tail_cb = _hf._encode_slab(to_host(d[n_full:]), tbl_np)
+        cb_parts.append(to_device(np.frombuffer(tail_cb.tobytes(), np.uint8)))
+        bit_parts.append(to_device(np.frombuffer(tail_bits, np.uint8)))
+    payload = jnp.concatenate([to_device(lens)] + cb_parts + bit_parts)
+    chunk_bytes = np.concatenate([to_host(p) for p in cb_parts]).view("<u2")
     return payload, dict({"n": n}, **_hf.offset_table(chunk_bytes))
 
 
@@ -405,20 +406,20 @@ def hf_decode_device(payload, header: dict):
     if usable:
         src = payload if is_device(payload) else None
         hp = None if src is not None else _host_u8(payload)
-        lens = np.asarray(src[:256]) if src is not None else hp[:256]
+        lens = to_host(src[:256]) if src is not None else hp[:256]
         maxlen = int(lens.max(initial=0))
         total = (int(src.size) if src is not None else hp.size) - 256 - 2 * nchunks
         usable = 0 < maxlen <= _hf.MAXLEN and 0 <= total <= _HF_DEC_MAX_BYTES
     if not usable:
-        return jnp.asarray(_hf.decode(_host_u8(payload), header))
+        return to_device(_hf.decode(_host_u8(payload), header))
     _, lens_c, first_code, sym_table, offsets, counts = _hf.canonical_codes(
         lens.astype(np.uint8)
     )
-    lut = jnp.asarray(
+    lut = to_device(
         _hf._pair_lut(first_code, counts, sym_table, offsets, maxlen).astype(np.uint32)
     )
     bits0 = 256 + 2 * nchunks
-    bits = src[bits0:] if src is not None else jnp.asarray(hp[bits0:])
+    bits = src[bits0:] if src is not None else to_device(hp[bits0:])
     # pow2-bucketed word allocation: +8 bytes slack like the numpy _be32,
     # padded with zeros so garbage lanes read zeros, not uninitialized mem
     balloc = max(4096, 1 << (total + 8 - 1).bit_length())
@@ -429,7 +430,7 @@ def hf_decode_device(payload, header: dict):
     calloc = max(64, 1 << (nchunks - 1).bit_length())
     cur = np.zeros(calloc, np.uint32)
     cur[:nchunks] = byte_off * np.uint32(8)
-    out_t = _hf_dec(be, jnp.asarray(cur), lut, maxlen)
+    out_t = _hf_dec(be, to_device(cur), lut, maxlen)
     return out_t[:, :nchunks].T.reshape(-1)[:n]
 
 
@@ -468,7 +469,7 @@ def _rr_encode_device(data, k: int, zero_mode: bool):
     if nsym == 0:
         z = np.zeros(0, np.uint8)
         payload, header = _rre._serialize(z, [], [], z, n, k, 0)
-        return jnp.asarray(np.frombuffer(payload, np.uint8)), header
+        return to_device(np.frombuffer(payload, np.uint8)), header
     nsym_p = -(-nsym // _SYM_PAD) * _SYM_PAD  # row bucket: bounds recompiles
     pad = nsym_p * k - n
     if pad:
@@ -478,14 +479,14 @@ def _rr_encode_device(data, k: int, zero_mode: bool):
     # kept-row compaction: the scan's output indices are the flag
     # positions; flatnonzero rides the host (XLA:CPU scatters are slow,
     # its gathers are not), the row gather stays on device
-    kept_idx = np.flatnonzero(np.asarray(flags))
+    kept_idx = np.flatnonzero(to_host(flags))
     count = int(kept_idx.size)
     alloc = max(-(-count // _SYM_PAD) * _SYM_PAD, _SYM_PAD)
     idx = np.zeros(alloc, np.int32)
     idx[:count] = kept_idx
-    kept_p = _rr_gather(viewp, jnp.asarray(idx))
+    kept_p = _rr_gather(viewp, to_device(idx))
     # the packed bitmap (1/8k of the stream) is all the host recursion needs
-    bitmap = np.asarray(bitmap_p)[: (nsym + 7) // 8]
+    bitmap = to_host(bitmap_p)[: (nsym + 7) // 8]
     top, levels, sizes = _rre._compress_bitmap(bitmap)
     header = {"n": n, "k": k, "nsym": nsym}
     meta = (
@@ -494,7 +495,7 @@ def _rr_encode_device(data, k: int, zero_mode: bool):
     )
     head = meta + top.tobytes() + b"".join(lv.tobytes() for lv in levels)
     payload = jnp.concatenate(
-        [jnp.asarray(np.frombuffer(head, np.uint8)), kept_p[:count].reshape(-1)]
+        [to_device(np.frombuffer(head, np.uint8)), kept_p[:count].reshape(-1)]
     )
     return payload, header
 
@@ -535,12 +536,12 @@ def _rr_decode_device(payload, header: dict, zero_mode: bool):
         return jnp.zeros(0, jnp.uint8)
     if "top" in header:  # legacy hex-in-JSON header: host reference path
         dec = _rre.rze_decode if zero_mode else _rre.rre_decode
-        return jnp.asarray(dec(_host_u8(payload).tobytes(), header))
+        return to_device(dec(_host_u8(payload).tobytes(), header))
     src = payload if is_device(payload) else None
     hp = None if src is not None else _host_u8(payload)
 
     def pull(a, b):
-        return np.asarray(src[a:b]) if src is not None else hp[a:b]
+        return to_host(src[a:b]) if src is not None else hp[a:b]
 
     # the recursive-bitmap metadata is tiny (1/8k of the stream): pull it
     # to host for the level recursion, keep the kept rows device-side
@@ -557,7 +558,7 @@ def _rr_decode_device(payload, header: dict, zero_mode: bool):
         off += ls
     bitmap = _rre._decompress_bitmap(top, levels, sizes)
     count = int(np.unpackbits(bitmap, count=nsym).sum())
-    kept = src[off:] if src is not None else jnp.asarray(hp[off:])
+    kept = src[off:] if src is not None else to_device(hp[off:])
     # bucketed allocations (pad rows/bits zero) bound recompiles
     nsym_p = -(-nsym // _SYM_PAD) * _SYM_PAD
     bm = np.zeros(nsym_p // 8, np.uint8)
@@ -566,7 +567,7 @@ def _rr_decode_device(payload, header: dict, zero_mode: bool):
     kept_p = jnp.concatenate(
         [kept, jnp.zeros(alloc * k - count * k, jnp.uint8)]
     ).reshape(alloc, k)
-    rows = _rr_expand(jnp.asarray(bm), kept_p, zero_mode)
+    rows = _rr_expand(to_device(bm), kept_p, zero_mode)
     return rows.reshape(-1)[:n]
 
 

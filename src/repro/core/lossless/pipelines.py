@@ -38,6 +38,7 @@ import struct
 
 import numpy as np
 
+from ..spans import span, to_host
 from .stages import get_stage
 
 _MAGIC = b"LLP2"
@@ -111,16 +112,17 @@ def encode(data, pipeline: str | tuple) -> bytes:
     recs = []
     for name in stages:
         st = get_stage(name)
-        if device and st.encode_device is not None:
-            payload, hdr = st.encode_device(cur)
-            nxt = payload  # device uint8 array: the stream stays resident
-        else:
-            if device:  # host-only stage: the stream drops to host for good
-                cur = np.asarray(cur)
-                device = False
-            payload, hdr = st.encode(cur)
-            nxt = np.frombuffer(payload, np.uint8) if isinstance(payload, bytes) else payload
-        hb = st.pack_header(hdr)
+        with span("encode." + name):
+            if device and st.encode_device is not None:
+                payload, hdr = st.encode_device(cur)
+                nxt = payload  # device uint8 array: the stream stays resident
+            else:
+                if device:  # host-only stage: the stream drops to host for good
+                    cur = to_host(cur)
+                    device = False
+                payload, hdr = st.encode(cur)
+                nxt = np.frombuffer(payload, np.uint8) if isinstance(payload, bytes) else payload
+            hb = st.pack_header(hdr)
         if nxt.size + len(hb) >= cur.size and cur.size > 0:
             recs.append((name, 1, b""))  # stage expands: store-through
             continue
@@ -131,7 +133,7 @@ def encode(data, pipeline: str | tuple) -> bytes:
     for name, flags, hb in recs:
         nb = name.encode()
         out += struct.pack("<BB", flags, len(nb)) + nb + struct.pack("<I", len(hb)) + hb
-    out += np.asarray(cur).tobytes()
+    out += to_host(cur).tobytes()
     return bytes(out)
 
 
@@ -166,13 +168,14 @@ def decode(buf, *, device: bool = False):
                 continue
             st = get_stage(name)
             hdr = st.unpack_header(hb)
-            if device and st.decode_device is not None:
-                cur = st.decode_device(cur, hdr)  # device uint8 stream
-                continue
-            if _is_jax(cur):  # twin-less stage: pull the stream to host
-                cur = np.asarray(cur)
-            out = st.decode(cur, hdr)
-            cur = out.tobytes() if isinstance(out, np.ndarray) else out
+            with span("decode." + name):
+                if device and st.decode_device is not None:
+                    cur = st.decode_device(cur, hdr)  # device uint8 stream
+                    continue
+                if _is_jax(cur):  # twin-less stage: pull the stream to host
+                    cur = to_host(cur)
+                out = st.decode(cur, hdr)
+                cur = out.tobytes() if isinstance(out, np.ndarray) else out
     else:
         # legacy stream: u32 length-prefixed JSON meta, dict headers (whose
         # hex-blob fields the twins would host-fallback on anyway)
@@ -189,7 +192,7 @@ def decode(buf, *, device: bool = False):
 
         return engine.as_device_u8(cur)
     if _is_jax(cur):
-        return np.asarray(cur).reshape(-1)
+        return to_host(cur).reshape(-1)
     return np.frombuffer(cur, np.uint8)
 
 

@@ -80,9 +80,11 @@ def reorder_codes_batch_device(grids, stride: int = ANCHOR_STRIDE, reorder: bool
     applied as one jnp gather; ``grids`` is a jax array (batch, *shape)."""
     import jax.numpy as jnp
 
+    from .spans import to_device
+
     shape = tuple(int(s) for s in grids.shape[1:])
     perm = level_permutation(shape, stride)[0] if reorder else flat_permutation(shape, stride)
-    return jnp.take(grids.reshape(grids.shape[0], -1), jnp.asarray(perm), axis=1).reshape(-1)
+    return jnp.take(grids.reshape(grids.shape[0], -1), to_device(perm), axis=1).reshape(-1)
 
 
 def restore_codes_batch(seq: np.ndarray, batch: int, shape: tuple[int, ...], fill, dtype, stride: int = ANCHOR_STRIDE, reorder: bool = True) -> np.ndarray:
@@ -101,7 +103,7 @@ def _restore_gather(shape: tuple[int, ...], stride: int, reorder: bool):
     anchors, masked off); the inverse-scatter becomes take+where, which is
     the fast direction on XLA:CPU (its scatters run ~10x behind gathers).
     """
-    import jax.numpy as jnp
+    from .spans import to_device
 
     if reorder:
         pos = level_permutation(shape, stride)[1]
@@ -110,7 +112,7 @@ def _restore_gather(shape: tuple[int, ...], stride: int, reorder: bool):
         pos = np.full(int(np.prod(shape)), -1, np.int64)
         pos[perm] = np.arange(perm.size)
     idx = np.where(pos >= 0, pos, 0).astype(np.int32)
-    return jnp.asarray(idx), jnp.asarray(pos >= 0)
+    return to_device(idx), to_device(pos >= 0)
 
 
 def restore_codes_batch_device(seq, ix, batch: int, shape: tuple[int, ...], fill):

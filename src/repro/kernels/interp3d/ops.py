@@ -9,10 +9,10 @@ container equals the jax predictor's byte for byte.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.predictor import quant_steps
+from repro.core.spans import to_device, to_host
 
 from .interp3d import LANES, interp3d_compress
 
@@ -33,9 +33,9 @@ def compress_blocks_pallas(blocks: np.ndarray, twoeb: float, steps, anchor_every
     pad = (-nb) % LANES
     if pad:
         blocks = np.concatenate([blocks, np.zeros((pad,) + blocks.shape[1:], blocks.dtype)], 0)
-    bt = jnp.asarray(np.moveaxis(blocks, 0, -1))  # (B,B,B,nb')
+    bt = to_device(np.moveaxis(blocks, 0, -1))  # (B,B,B,nb')
     codes, recon = interp3d_compress(bt, *quant_steps(0.5 * twoeb), steps, anchor_every, interpret)
-    mv = lambda a: np.moveaxis(np.asarray(a), -1, 0)[:nb]
+    mv = lambda a: np.moveaxis(to_host(a), -1, 0)[:nb]
     codes = mv(codes)
     return codes, codes == 0, mv(recon)
 

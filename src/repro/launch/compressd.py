@@ -30,8 +30,9 @@ typed exception from :mod:`repro.core.errors`). Ops:
     payload = any v1/v2/v3 container; response payload = raw float32
     field bytes, shape/dtype in the header.
 ``stats``
-    per-stream telemetry (CR, MB/s, request counts), queue depth,
-    in-flight bytes, plan-cache hit rate, totals.
+    per-stream telemetry (CR, MB/s, request counts, self seconds by
+    compressor span), queue depth, in-flight bytes, plan-cache hit rate,
+    totals.
 ``health``
     cheap liveness + load snapshot (draining flag, in-flight bytes,
     queued admissions). Like ``stats`` it bypasses admission entirely,
@@ -619,8 +620,16 @@ class CompressdServer:
             rec = self._streams[name] = {
                 "requests": 0, "errors": 0, "raw_bytes": 0, "comp_bytes": 0,
                 "seconds": 0.0, "plan_cache_hits": 0, "plan_cache_misses": 0,
+                "spans": {},  # self seconds by program span (repro.core.spans)
             }
         return rec
+
+    @staticmethod
+    def _add_spans(rec: dict, tel: dict) -> None:
+        trace = tel.get("trace")
+        if trace is not None:
+            for name, s in trace.self_seconds().items():
+                rec["spans"][name] = rec["spans"].get(name, 0.0) + s
 
     def _handle(self, header: dict, payload: bytes) -> tuple[dict, bytes]:
         op = str(header.get("op", ""))
@@ -688,6 +697,7 @@ class CompressdServer:
                 rec["plan_cache_hits"] += 1
             elif cache_state == "miss":
                 rec["plan_cache_misses"] += 1
+            self._add_spans(rec, tel)
         info = {
             "ok": True, "cr": len(payload) / max(len(buf), 1), "seconds": dt,
             "mbps": len(payload) / dt / 1e6 if dt > 0 else 0.0,
@@ -707,6 +717,7 @@ class CompressdServer:
                 self._stream(stream)["errors"] += 1
             raise
         dt = time.perf_counter() - t0
+        tel = comp.last_telemetry or {}
         raw = out.tobytes()
         with self._tlock:
             rec = self._stream(stream)
@@ -714,6 +725,7 @@ class CompressdServer:
             rec["raw_bytes"] += len(raw)
             rec["comp_bytes"] += len(payload)
             rec["seconds"] += dt
+            self._add_spans(rec, tel)
         info = {"ok": True, "shape": list(out.shape), "dtype": str(out.dtype),
                 "seconds": dt, "mbps": len(raw) / dt / 1e6 if dt > 0 else 0.0}
         return info, raw
@@ -733,7 +745,7 @@ class CompressdServer:
             totals = {"requests": 0, "errors": self._errors, "raw_bytes": 0,
                       "comp_bytes": 0, "seconds": 0.0}
             for name, rec in self._streams.items():
-                view = dict(rec)
+                view = dict(rec, spans=dict(rec["spans"]))
                 view["cr"] = rec["raw_bytes"] / max(rec["comp_bytes"], 1)
                 view["mbps"] = (rec["raw_bytes"] / rec["seconds"] / 1e6
                                 if rec["seconds"] > 0 else 0.0)

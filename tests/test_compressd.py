@@ -119,6 +119,18 @@ def test_roundtrip_and_plan_cache_hit(server):
     assert rec["cr"] > 0 and rec["mbps"] > 0
 
 
+def test_stream_stats_carry_span_self_seconds(server):
+    x = _field(5)
+    with CompressdClient(server.address, stream="t-spans") as c:
+        c.decompress(c.compress(x, eb=1e-3, pipeline="tp", autotune=False))
+        rec = c.stats()["streams"]["t-spans"]
+    sp = rec["spans"]
+    assert {"compress", "compress.prep", "compress.encode", "compress.verify",
+            "decompress", "decompress.lossless", "decompress.restore"} <= set(sp)
+    assert all(v >= -1e-9 for v in sp.values())
+    assert sum(sp.values()) <= rec["seconds"]
+
+
 def test_spec_variants_roundtrip(server):
     x = _field(4)
     with CompressdClient(server.address) as c:
