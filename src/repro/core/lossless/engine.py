@@ -20,10 +20,13 @@ The shape of each kernel follows the GPU literature the paper builds on
   prefix-sum/boundary-gather reduction into the big-endian word stream —
   the same arithmetic as the numpy encoder, so the bitstream is
   identical.
-* **rre/rze** — flag computation and MSB-first bitmap packing run on
-  device; the kept-symbol compaction is a device row-gather addressed by
-  the flag positions; only the packed bitmap (1/8k of the stream) crosses
-  to host for the tiny recursive-bitmap recursion and header assembly.
+* **rre/rze** — flag computation, MSB-first bitmap packing and the
+  payload's exact size (kept rows and every bitmap level's non-zero
+  bytes, fixed-shape reductions) run on device; a caller that would store
+  the stage through learns so from those few scalars alone. Otherwise the
+  kept-symbol compaction is a device row-gather addressed by the flag
+  positions; only the packed bitmap (1/8k of the stream) crosses to host
+  for the tiny recursive-bitmap recursion and header assembly.
 * **bit1** — the plane shuffle runs through the Pallas bitshuffle kernel
   on TPU and a jnp twin elsewhere (identical bit layout either way).
 * **tcms** — bytewise sign-magnitude bijection, one fused ``where``.
@@ -435,12 +438,27 @@ def hf_decode_device(payload, header: dict):
 
 
 # ------------------------------------------------------------------ rre/rze
+def _level_bytes(nsym: int) -> list[int]:
+    """Byte sizes of the recursive bitmap's levels for ``nsym`` flags, the
+    top level last (``rre._compress_bitmap``'s recursion, sizes only)."""
+    sizes = [(nsym + 7) // 8]
+    while sizes[-1] > _rre._BITMAP_FLOOR:
+        sizes.append((sizes[-1] + 7) // 8)
+    return sizes
+
+
 @functools.partial(jax.jit, static_argnums=(2,))
 def _rr_flags(viewp: jax.Array, nsym: jax.Array, zero_mode: bool):
-    """Flags + packed bitmap for RRE (``zero_mode=False``) / RZE.
+    """Flags, packed bitmap and payload counts for RRE (``zero_mode=False``)
+    / RZE.
 
     ``viewp``: (nsym_p, k) u8 rows, nsym_p % 8 == 0, rows past ``nsym``
-    zero. Returns (flags, MSB-first packed bitmap over nsym_p flags).
+    zero. Returns (flags, MSB-first packed bitmap over nsym_p flags,
+    counts): ``counts`` is i32 [kept rows, non-zero bytes of bitmap level
+    0, 1, ...], one entry per level ``nsym_p`` flags would recurse through
+    (``nsym``'s levels are a prefix of them). Byte j of level i is non-zero
+    iff a flag in [j * 8^(i+1), (j+1) * 8^(i+1)) is set, and the pad flags
+    are false, so every count is a fixed-shape reduction.
     """
     nsym_p = viewp.shape[0]
     v32 = viewp.astype(jnp.int32)  # i32 lanes: XLA:CPU scalarizes u8 math
@@ -454,7 +472,21 @@ def _rr_flags(viewp: jax.Array, nsym: jax.Array, zero_mode: bool):
     # MSB-first bit packing (np.packbits layout)
     wts = jnp.left_shift(jnp.int32(1), 7 - jax.lax.iota(jnp.int32, 8))
     bitmap = (flags.reshape(-1, 8) * wts).sum(axis=1).astype(jnp.uint8)
-    return flags, bitmap
+    counts = [flags.sum(dtype=jnp.int32)]
+    nz = flags
+    for _ in _level_bytes(nsym_p)[:-1]:
+        nz = jnp.pad(nz, (0, -nz.size % 8)).reshape(-1, 8).any(axis=1)
+        counts.append(nz.sum(dtype=jnp.int32))
+    return flags, bitmap, jnp.stack(counts)
+
+
+def _rr_size(counts: np.ndarray, nsym: int, k: int) -> int:
+    """Exact payload bytes from ``_rr_flags``' counts: the meta, the
+    non-zero bytes of every recursed level, the top level, the kept rows."""
+    levels = _level_bytes(nsym)
+    n_levels = len(levels) - 1
+    body = sum(int(c) for c in counts[1 : 1 + n_levels])
+    return 4 + 16 * n_levels + body + levels[-1] + int(counts[0]) * k
 
 
 @jax.jit
@@ -462,33 +494,39 @@ def _rr_gather(viewp: jax.Array, idx: jax.Array):
     return viewp[idx]
 
 
-def _rr_encode_device(data, k: int, zero_mode: bool):
+def _rr_encode_device(data, k: int, zero_mode: bool, limit=None):
+    """Shared RRE/RZE device encode; see ``rre_encode_device``."""
     d = as_device_u8(data)
     n = int(d.size)
     nsym = -(-n // k)
+    header = {"n": n, "k": k, "nsym": nsym}
+    if nsym == 0:
+        counts = np.zeros(1, np.int32)
+    else:
+        nsym_p = -(-nsym // _SYM_PAD) * _SYM_PAD  # row bucket: bounds recompiles
+        pad = nsym_p * k - n
+        if pad:
+            d = jnp.concatenate([d, jnp.zeros(pad, jnp.uint8)])
+        viewp = d.reshape(nsym_p, k)
+        flags, bitmap_p, counts = _rr_flags(viewp, jnp.int32(nsym), zero_mode)
+        counts = to_host(counts)
+    if limit is not None and _rr_size(counts, nsym, k) >= limit(header):
+        return None, header  # decided from the size: no compaction
     if nsym == 0:
         z = np.zeros(0, np.uint8)
-        payload, header = _rre._serialize(z, [], [], z, n, k, 0)
+        payload, _ = _rre._serialize(z, [], [], z, n, k, 0)
         return to_device(np.frombuffer(payload, np.uint8)), header
-    nsym_p = -(-nsym // _SYM_PAD) * _SYM_PAD  # row bucket: bounds recompiles
-    pad = nsym_p * k - n
-    if pad:
-        d = jnp.concatenate([d, jnp.zeros(pad, jnp.uint8)])
-    viewp = d.reshape(nsym_p, k)
-    flags, bitmap_p = _rr_flags(viewp, jnp.int32(nsym), zero_mode)
     # kept-row compaction: the scan's output indices are the flag
     # positions; flatnonzero rides the host (XLA:CPU scatters are slow,
     # its gathers are not), the row gather stays on device
-    kept_idx = np.flatnonzero(to_host(flags))
-    count = int(kept_idx.size)
+    count = int(counts[0])
     alloc = max(-(-count // _SYM_PAD) * _SYM_PAD, _SYM_PAD)
     idx = np.zeros(alloc, np.int32)
-    idx[:count] = kept_idx
+    idx[:count] = np.flatnonzero(to_host(flags))
     kept_p = _rr_gather(viewp, to_device(idx))
     # the packed bitmap (1/8k of the stream) is all the host recursion needs
     bitmap = to_host(bitmap_p)[: (nsym + 7) // 8]
     top, levels, sizes = _rre._compress_bitmap(bitmap)
-    header = {"n": n, "k": k, "nsym": nsym}
     meta = (
         np.asarray([top.size, len(levels)], "<u2").tobytes()
         + np.asarray(list(sizes) + [lv.size for lv in levels], "<u8").tobytes()
@@ -500,14 +538,22 @@ def _rr_encode_device(data, k: int, zero_mode: bool):
     return payload, header
 
 
-def rre_encode_device(data, k: int):
-    """Device RRE-k; payload bytes == ``rre.rre_encode``'s."""
-    return _rr_encode_device(data, k, zero_mode=False)
+def rre_encode_device(data, k: int, limit=None):
+    """Device RRE-k; payload bytes == ``rre.rre_encode``'s.
+
+    The payload's exact size comes from the flags first. ``limit``, if
+    given, maps the header to the payload size at which the caller stores
+    the stage through (``pipelines.encode``); a payload that reaches it is
+    returned as ``None`` after one pull of a few scalars, and no kept-row
+    compaction, bitmap pull or concatenation runs.
+    """
+    return _rr_encode_device(data, k, False, limit)
 
 
-def rze_encode_device(data, k: int):
-    """Device RZE-k; payload bytes == ``rre.rze_encode``'s."""
-    return _rr_encode_device(data, k, zero_mode=True)
+def rze_encode_device(data, k: int, limit=None):
+    """Device RZE-k; payload bytes == ``rre.rze_encode``'s; ``limit`` as
+    for :func:`rre_encode_device`."""
+    return _rr_encode_device(data, k, True, limit)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))

@@ -17,7 +17,10 @@ an ``encode_device`` twin (repro.core.lossless.engine) runs jit-compiled on
 the device and the stream chains between stages as a device array — the
 bytes only land on host once, in the final packed stream. The engine's
 bit-identity contract makes the result byte-equal to the numpy path, so
-the choice of path is invisible to decoders and golden fixtures. A stage
+the choice of path is invisible to decoders and golden fixtures. A twin
+that sizes its payload first (rre/rze) is handed the store-through limit
+and, where its payload would reach it, skips building it: the record is
+the same store-through either way. A stage
 without a device twin (e.g. ``zstd``) drops the stream to host and the
 remaining stages run the numpy path. ``decode(buf, device=True)`` is the
 symmetric read path: stages with ``decode_device`` twins chain the stream
@@ -33,12 +36,13 @@ through the same stage registry, so old containers keep working.
 """
 from __future__ import annotations
 
+import inspect
 import json
 import struct
 
 import numpy as np
 
-from ..spans import span, to_host
+from ..spans import count, span, to_host
 from .stages import get_stage
 
 _MAGIC = b"LLP2"
@@ -100,6 +104,16 @@ def _is_jax(data) -> bool:
     return not isinstance(data, np.ndarray) and "jax" in type(data).__module__
 
 
+def _store_limit(st, cur):
+    """Keyword for ``st``'s device twin: the payload size at which the
+    stage stores ``cur`` through, from the header (the rule below). A twin
+    without the keyword, and an empty stream (never stored through), get
+    none."""
+    if not cur.size or "limit" not in inspect.signature(st.encode_device).parameters:
+        return {}
+    return {"limit": lambda hdr: cur.size - len(st.pack_header(hdr))}
+
+
 def encode(data, pipeline: str | tuple) -> bytes:
     stages = _resolve(pipeline)
     device = _is_jax(data)
@@ -114,8 +128,9 @@ def encode(data, pipeline: str | tuple) -> bytes:
         st = get_stage(name)
         with span("encode." + name):
             if device and st.encode_device is not None:
-                payload, hdr = st.encode_device(cur)
-                nxt = payload  # device uint8 array: the stream stays resident
+                # device uint8 array: the stream stays resident; None: the
+                # twin's size alone decided store-through
+                nxt, hdr = st.encode_device(cur, **_store_limit(st, cur))
             else:
                 if device:  # host-only stage: the stream drops to host for good
                     cur = to_host(cur)
@@ -123,8 +138,11 @@ def encode(data, pipeline: str | tuple) -> bytes:
                 payload, hdr = st.encode(cur)
                 nxt = np.frombuffer(payload, np.uint8) if isinstance(payload, bytes) else payload
             hb = st.pack_header(hdr)
-        if nxt.size + len(hb) >= cur.size and cur.size > 0:
+        if nxt is None or (nxt.size + len(hb) >= cur.size and cur.size > 0):
             recs.append((name, 1, b""))  # stage expands: store-through
+            count("store_through", 1)
+            if nxt is None:
+                count("store_through_early", 1)
             continue
         recs.append((name, 0, hb))
         cur = nxt

@@ -18,7 +18,10 @@ bit-plane shuffle, ...). Each stage is self-describing:
   ``encode`` taking a ``jax.Array`` uint8 stream and returning a *device*
   uint8 payload, byte-identical to ``encode``'s (the engine contract, see
   repro.core.lossless.engine). Stages without one fall back to the numpy
-  path when a pipeline runs device-resident.
+  path when a pipeline runs device-resident. A twin that also takes
+  ``limit`` (a function of its header: the payload size at which the
+  pipeline stores the stage through) may return ``None`` for a payload
+  that reaches it, without building it.
 * ``decode_device(payload, header) -> jax.Array`` — optional device twin
   of ``decode`` under the same bit-identity contract, accepting host
   bytes-like or device uint8 payloads and returning a *device* uint8
@@ -252,11 +255,13 @@ def _zstd_decode(payload: bytes, header: dict) -> np.ndarray:
 # Device twins resolve the engine lazily: repro.core.lossless.engine pulls
 # in jax, which host-only consumers of this module never need.
 
-def _dev(fn_name: str, **fixed):
-    def call(data, _fn=fn_name, _fixed=fixed):
+def _dev(fn_name: str, sized: bool = False, **fixed):
+    # only a sized twin (rre/rze) acts on the store-through limit
+    def call(data, limit=None, _fn=fn_name, _fixed=fixed):
         from . import engine
 
-        return getattr(engine, _fn)(data, **_fixed)
+        kw = dict(_fixed, limit=limit) if sized else _fixed
+        return getattr(engine, _fn)(data, **kw)
 
     return call
 
@@ -289,11 +294,11 @@ def _register_builtins() -> None:
     for k in (1, 2, 4, 8):
         register_stage(f"rre{k}", (lambda d, k=k: _rre.rre_encode(d, k)), _rre.rre_decode,
                        estimate=_est_rre(k), pack_header=_pack_rre, unpack_header=_unpack_rre,
-                       encode_device=_dev("rre_encode_device", k=k),
+                       encode_device=_dev("rre_encode_device", sized=True, k=k),
                        decode_device=_devd("rre_decode_device"))
         register_stage(f"rze{k}", (lambda d, k=k: _rre.rze_encode(d, k)), _rre.rze_decode,
                        estimate=_est_rze(k), pack_header=_pack_rre, unpack_header=_unpack_rre,
-                       encode_device=_dev("rze_encode_device", k=k),
+                       encode_device=_dev("rze_encode_device", sized=True, k=k),
                        decode_device=_devd("rze_decode_device"))
         register_stage(f"tcms{k}", (lambda d, k=k: _tcms.tcms_encode(d, k)), _tcms.tcms_decode,
                        estimate=_est_unit, pack_header=_pack_tcms, unpack_header=_unpack_tcms,

@@ -60,6 +60,78 @@ def test_rre_rze_device_bit_identical(k, name, data):
     assert (hdev, np.asarray(pdev).tobytes()) == (hdr, payload), name
 
 
+def _sparse(nbytes: int, seed: int) -> np.ndarray:
+    """Zeros with random bytes at heavy-tailed gaps, so that every bitmap
+    level holds zero and non-zero bytes."""
+    rng = np.random.default_rng(seed)
+    out = np.zeros(nbytes, np.uint8)
+    pos = np.cumsum((rng.pareto(0.7, nbytes // 16 + 1) * 3).astype(np.int64) + 1)
+    pos = pos[pos < nbytes]
+    out[pos] = rng.integers(1, 256, pos.size)
+    return out
+
+
+# bitmap level i holds ceil(nsym / 8^(i+1)) bytes and recurses while that
+# is over rre._BITMAP_FLOOR (64): one below, at and one above the floor for
+# the top level of 0..3 recursed levels
+FLOOR_NSYM = {f"L{lv}-top{top}": top * 8 ** (lv + 1) - (top == 65) * (8 ** (lv + 1) - 1)
+              for lv in range(4) for top in (63, 64, 65)}
+
+
+def _size_stream(name: str, k: int) -> np.ndarray:
+    rng = np.random.default_rng(k)
+    if name in FLOOR_NSYM:
+        return _sparse(FLOOR_NSYM[name] * k, FLOOR_NSYM[name])
+    return {
+        "empty": lambda: np.zeros(0, np.uint8),
+        "one-byte": lambda: np.array([200], np.uint8),
+        "constant": lambda: np.full(3000, 7, np.uint8),
+        "random": lambda: rng.integers(0, 256, 5000, dtype=np.uint8),
+        "runs": lambda: np.repeat(rng.integers(0, 4, 60, dtype=np.uint8), 101),
+        "padded": lambda: _sparse(5000 * k + 1, k),  # n % k == 1 for k > 1
+    }[name]()
+
+
+@pytest.mark.parametrize("name", ["empty", "one-byte", "constant", "random", "runs", "padded", *FLOOR_NSYM])
+@pytest.mark.parametrize("zero_mode", [False, True])
+@pytest.mark.parametrize("k", [1, 2, 4, 8])
+def test_rr_device_size_is_exact(k, zero_mode, name):
+    """The twin sizes its payload from the flags alone, exactly: with the
+    store-through limit at the reference payload's size it returns no
+    payload (``pipelines.encode`` stores through), one byte above it it
+    returns the reference payload."""
+    data = _size_stream(name, k)
+    ref, hdr = (rre.rze_encode if zero_mode else rre.rre_encode)(data, k)
+    twin = eng.rze_encode_device if zero_mode else eng.rre_encode_device
+    d = jnp.asarray(data)
+    seen = []
+    got, h = twin(d, k, limit=lambda h: seen.append(h) or len(ref))
+    assert got is None and h == hdr == seen[0]
+    got, h = twin(d, k, limit=lambda h: len(ref) + 1)
+    assert (h, np.asarray(got).tobytes()) == (hdr, ref)
+
+
+@pytest.mark.parametrize("pipe", ["tp", "fz", "lvl"])
+def test_store_through_is_decided_from_the_size(pipe):
+    """A random stream makes rre/rze expand: the pipeline stores them
+    through from their size alone. A run-heavy one compacts: no stage is
+    decided early. Either way the bytes are the numpy path's."""
+    from repro.core import spans
+
+    rng = np.random.default_rng(5)
+    streams = {"random": rng.integers(0, 256, 200_000, dtype=np.uint8),
+               "runs": np.repeat(rng.integers(0, 4, 20, dtype=np.uint8), 10_000)}
+    early = {}
+    for name, data in streams.items():
+        with spans.call("compress") as rec:
+            buf = pp.encode(jnp.asarray(data), pipe)
+        assert buf == pp.encode(data, pipe), (pipe, name)
+        assert np.array_equal(pp.decode(buf), data), (pipe, name)
+        early[name] = rec.counters.get("store_through_early", 0)
+        assert rec.counters.get("store_through", 0) >= early[name]
+    assert early["random"] >= 1 and early["runs"] == 0, early
+
+
 @pytest.mark.parametrize("k", [1, 2, 4, 8])
 @pytest.mark.parametrize("name,data", STREAMS)
 def test_tcms_device_bit_identical(k, name, data):

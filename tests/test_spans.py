@@ -242,6 +242,25 @@ def test_compress_spans_cover_the_call():
     assert sum(s.seconds for s in rec.spans if s.parent < 0) >= 0.95 * rec.seconds
 
 
+def test_a_stream_stored_through_compiles_nothing_that_follows_its_data():
+    """Two random streams of one length whose kept-row counts fall in
+    different row buckets: rre1 expands on both, so the second encode is
+    decided from its size and compiles no program shaped by the count."""
+    from repro.core.lossless import pipelines
+
+    rng = np.random.default_rng(9)
+    a = rng.integers(0, 256, 200_000, dtype=np.uint8)
+    b = a.copy()
+    b[1::10] = b[:-1:10]  # one byte in ten repeats: ~10% fewer kept rows
+    recs = []
+    for data in (a, b):
+        with spans.call("compress") as rec:
+            pipelines.encode(jnp.asarray(data), "tp")
+        recs.append(rec)
+    assert [r.counters.get("store_through_early", 0) for r in recs] == [1, 1]
+    assert recs[1].counters["compiles"] == 0
+
+
 # Containers written before the spans existed, for the same fields: the
 # recording changes no byte.
 GOLDEN = {
